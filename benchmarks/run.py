@@ -67,6 +67,9 @@ def main() -> None:
                          "quickstart in ROADMAP.md")
     args, _ = ap.parse_known_args()
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import (chaos_bench, coproc_bench, decode_bench,
                             fig2_throughput, obs_bench, orbit_bench,
                             partition_sweep, precision_micro,

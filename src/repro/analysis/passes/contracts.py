@@ -37,10 +37,10 @@ KERNEL_CONTRACTS: Dict[str, KernelContract] = {
     "paged_attention_pallas": KernelContract(
         module="src/repro/kernels/paged_attention.py",
         kernel_fn="_paged_kernel",
-        grid_rank=3,
+        grid_rank=2,               # (sequence, table block); heads in-body
         num_scalar_prefetch=2,     # block_table + lengths ride ahead
         tail_guard=True,           # dead-block predication + init/finalize
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        dimension_semantics=("parallel", "arbitrary"),
         divisibility_assert=False,  # pool rows are whole pages by layout
         out_dtypes=("q.dtype",),
     ),

@@ -20,19 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:                                   # jax >= 0.4.35
-    from jax import shard_map as _shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-except (ImportError, TypeError):       # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _sm
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
-
 
 def pipeline_apply(mesh: Mesh, stage_axis: str,
                    stage_fns: Sequence[Callable],
@@ -88,9 +75,9 @@ def pipeline_apply(mesh: Mesh, stage_axis: str,
                                     jnp.arange(steps))
         return outs[None]              # leading stage dim for out_specs
 
-    fn = shard_map(body, mesh,
-                   in_specs=(P(stage_axis), P()),
-                   out_specs=P(stage_axis))
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(stage_axis), P()),
+                       out_specs=P(stage_axis), check_vma=False)
     outs_per_stage = fn(stage_params_stacked, micro_inputs)
     return outs_per_stage[-1]          # the last stage's buffer is the answer
 
@@ -134,24 +121,29 @@ def lm_two_stage_fns(cfg, plan, tp: int = 1):
     return stage0, stage1, (seg0, seg1)
 
 
-def split_lm_params_for_stages(params, cfg, plan, period: int):
+def split_lm_params_for_stages(params, cfg, plan, period: int,
+                               combine=None):
     """Split a monolithic LM param tree into per-stage trees with identical
     structure (required for stacking over the stage axis).  Stage trees are
-    padded with zero-size-compatible entries where a stage lacks a part."""
-    import jax.numpy as jnp
-    from repro.models.transformer import _slice_stack
+    padded with zero-size-compatible entries where a stage lacks a part.
 
+    ``combine(stage0_leaf, stage1_leaf)`` builds each stacked leaf (default
+    ``jnp.stack``); it runs leaf by leaf, so a caller that places each
+    stage's half on its own device never holds a whole stage copy at
+    once."""
+    import jax.numpy as jnp
+
+    if combine is None:
+        combine = lambda a, b: jnp.stack([a, b])
     seg0, seg1 = plan.segments[0], plan.segments[-1]
     lo = seg0.end // period
     table = params["embed"] if "lm_head" not in params else params["lm_head"]
     n0, n1 = lo, (seg1.end - seg1.start) // period
     assert n0 == n1, ("two-stage pipeline requires equal segment lengths; "
                       f"got {n0} vs {n1} super-blocks")
-    s0 = {"layers": _slice_stack(params["layers"], 0, lo),
-          "final_norm": jax.tree_util.tree_map(jnp.zeros_like,
-                                               params["final_norm"]),
-          "lm_head": jnp.zeros_like(table)}
-    s1 = {"layers": _slice_stack(params["layers"], lo, lo + n1),
-          "final_norm": params["final_norm"],
-          "lm_head": table}
-    return jax.tree_util.tree_map(lambda a, b: jnp.stack([a, b]), s0, s1)
+    tmap = jax.tree_util.tree_map
+    return {"layers": tmap(lambda a: combine(a[:lo], a[lo:lo + n1]),
+                           params["layers"]),
+            "final_norm": tmap(lambda a: combine(jnp.zeros_like(a), a),
+                               params["final_norm"]),
+            "lm_head": combine(jnp.zeros_like(table), table)}

@@ -1,14 +1,11 @@
 """Public jit'd wrappers for the Pallas kernels.
 
 Handles shape alignment (padding to block multiples), GQA kv expansion,
-and backend selection: on TPU the compiled kernels run natively; on CPU
-(this container) ``interpret=True`` executes the kernel bodies in Python
-for correctness validation.  ``REPRO_FORCE_INTERPRET=0`` disables the
-override on real hardware.
+and backend selection, which the backend alone decides: on a TPU the
+kernels always run compiled; elsewhere ``interpret=True`` executes the
+kernel bodies in Python for correctness validation.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -24,9 +21,6 @@ from repro.kernels.wkv import wkv_pallas
 
 
 def _interpret() -> bool:
-    env = os.environ.get("REPRO_FORCE_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
     return jax.default_backend() != "tpu"
 
 
@@ -144,13 +138,11 @@ def paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray, v_pool: jnp.ndarray,
     block-walk analogue at full native speed instead of the Pallas
     interpreter (which emulates the grid serially — fine for the
     equivalence tests that pin kernel-vs-reference numerics, hopeless
-    for a throughput benchmark).  ``REPRO_PAGED_PALLAS=1`` forces the
-    interpreted kernel for debugging.
+    for a throughput benchmark).
     """
-    if not _interpret() or os.environ.get("REPRO_PAGED_PALLAS") == "1":
-        return paged_attention_pallas(q, k_pool, v_pool, block_table,
-                                      lengths, interpret=_interpret())
-    return paged_attention_xla(q, k_pool, v_pool, block_table, lengths)
+    if _interpret():
+        return paged_attention_xla(q, k_pool, v_pool, block_table, lengths)
+    return paged_attention_pallas(q, k_pool, v_pool, block_table, lengths)
 
 
 @jax.jit

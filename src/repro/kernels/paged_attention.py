@@ -27,10 +27,13 @@ Block-table layout contract (shared with ``runtime.paging``):
     the index map clamps them to row 0 (the DMA must target *something*)
     and the kernel body is predicated off, so they contribute nothing.
 
-Grid is ``(B, KVp, MB)`` with the block sweep innermost and
-"arbitrary" semantics, so the online-softmax statistics (m, l, acc)
-stay VMEM-resident across a sequence's whole table walk — the decode
-analogue of `flash_attention.py`'s KV sweep.
+Grid is ``(B, MB)`` with the block sweep innermost and "arbitrary"
+semantics, so the online-softmax statistics (m, l, acc) stay
+VMEM-resident across a sequence's whole table walk — the decode
+analogue of `flash_attention.py`'s KV sweep.  Each grid step DMAs one
+whole pool row, ``(P, KVp, hd)``, and walks the KVp heads inside the
+body: a block's last two dims then equal the pool's, which is what the
+TPU's (8, 128) tiling rule asks of a block that is not tile-aligned.
 """
 from __future__ import annotations
 
@@ -42,16 +45,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
-
 NEG_INF = -1e30
 
 
 def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
                   m_ref, l_ref, acc_ref, *, page: int, num_blk: int,
-                  scale: float):
+                  kv_heads: int, scale: float):
     b_idx = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
@@ -63,29 +64,33 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
     # Skip blocks entirely past this sequence's length: the DMA engine
     # still fetched *a* row (the index map clamps dead table entries to
-    # row 0) but neither MXU matmul is issued for it.
+    # row 0) but no head's matmuls are issued for it.
     @pl.when(j * page < seq_len)
     def _block():
-        q = q_ref[0, 0].astype(jnp.float32) * scale       # [gp, hd]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)         # [P, hd]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # [gp, P]
-        pos = j * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < seq_len, s, NEG_INF)
+        gp = q_ref.shape[2]
+        pos = j * page + jax.lax.broadcasted_iota(jnp.int32, (gp, page), 1)
+        live = pos < seq_len
+        for h in range(kv_heads):                 # static walk over heads
+            q = q_ref[0, h].astype(jnp.float32) * scale       # [gp, hd]
+            k = k_ref[0, :, h, :].astype(jnp.float32)         # [P, hd]
+            v = v_ref[0, :, h, :].astype(jnp.float32)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(live, s, NEG_INF)                   # [gp, P]
 
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * corr + jnp.dot(
+                p, v, preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
 
     @pl.when(j == num_blk - 1)
     def _finalize():
-        o_ref[0, 0] = (acc_ref[...] /
-                       jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] /
+                    jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -103,36 +108,36 @@ def paged_attention_pallas(q: jnp.ndarray, k_pool: jnp.ndarray,
     mb = block_table.shape[1]
     scale = 1.0 / math.sqrt(hd)
     kernel = functools.partial(_paged_kernel, page=page, num_blk=mb,
-                               scale=scale)
+                               kv_heads=kvp, scale=scale)
 
-    def q_map(i, h, j, tbl, lens):
-        return (i, h, 0, 0)
+    def q_map(i, j, tbl, lens):
+        return (i, 0, 0, 0)
 
-    def kv_map(i, h, j, tbl, lens):
+    def kv_map(i, j, tbl, lens):
         # dead entries (-1) clamp to row 0; the body is predicated off
-        return (jnp.maximum(tbl[i, j], 0), 0, h, 0)
+        return (jnp.maximum(tbl[i, j], 0), 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kvp, mb),
+        grid=(b, mb),
         in_specs=[
-            pl.BlockSpec((1, 1, gp, hd), q_map),
-            pl.BlockSpec((1, page, 1, hd), kv_map),
-            pl.BlockSpec((1, page, 1, hd), kv_map),
+            pl.BlockSpec((1, kvp, gp, hd), q_map),
+            pl.BlockSpec((1, page, kvp, hd), kv_map),
+            pl.BlockSpec((1, page, kvp, hd), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, gp, hd), q_map),
+        out_specs=pl.BlockSpec((1, kvp, gp, hd), q_map),
         scratch_shapes=[
             # VMEM-resident online-softmax statistics across the walk
-            pltpu.VMEM((gp, 1), jnp.float32),
-            pltpu.VMEM((gp, 1), jnp.float32),
-            pltpu.VMEM((gp, hd), jnp.float32),
+            pltpu.VMEM((kvp, gp, 1), jnp.float32),
+            pltpu.VMEM((kvp, gp, 1), jnp.float32),
+            pltpu.VMEM((kvp, gp, hd), jnp.float32),
         ],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvp, gp, hd), q.dtype),
-        compiler_params=_compat.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(block_table, lengths, q, k_pool, v_pool)

@@ -15,7 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
 
 QMAX = 127.0
 DEFAULT_BM = 256
@@ -48,7 +47,7 @@ def rowwise_quant_pallas(x: jnp.ndarray, bm: int = DEFAULT_BM,
                    pl.BlockSpec((bm, 1), lambda i: (i, 0))],
         out_shape=[jax.ShapeDtypeStruct((m, k), jnp.int8),
                    jax.ShapeDtypeStruct((m, 1), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x)

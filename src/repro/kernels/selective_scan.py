@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
 
 DEFAULT_BD = 512
 DEFAULT_Q = 256
@@ -87,7 +86,7 @@ def selective_scan_pallas(x: jnp.ndarray, dt: jnp.ndarray, b: jnp.ndarray,
         out_specs=pl.BlockSpec((1, q, bd), lambda i, j, k: (i, k, j)),
         out_shape=jax.ShapeDtypeStruct((bsz, s, dim), x.dtype),
         scratch_shapes=[pltpu.VMEM((bd, n), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, dt, b, c, a, d)

@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
 
 DEFAULT_Q = 128
 
@@ -66,7 +65,7 @@ def wkv_pallas(r: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((bsz, s, h, n), r.dtype),
         scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(r, k, v, w, u)

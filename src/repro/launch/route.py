@@ -22,6 +22,7 @@ import json
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.router import SLO_CLASSES
 from repro.serving import FaultSpec, FleetSpec, PoolSpec
 from repro.serving.traffic import open_loop
@@ -157,6 +158,7 @@ def main():
     ap.add_argument("--json", action="store_true",
                     help="print raw JSON only (for scripting)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     report = {"vision": vision_section(args)}
     if args.lm:

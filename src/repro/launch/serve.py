@@ -16,6 +16,7 @@ import argparse
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.serving import FleetSpec, PoolSpec
 
 
@@ -31,6 +32,7 @@ def main():
                     help="write a Chrome trace_event JSON of the run "
                          "(open in Perfetto / chrome://tracing)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     spec = FleetSpec(
         pools=[PoolSpec("serve", ("tpu_v5e_bf16",), backend="engine",

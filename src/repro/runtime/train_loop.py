@@ -16,12 +16,12 @@ from typing import Any, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import sharding as shard
 from repro.configs.base import MeshConfig, ModelConfig, ShapeConfig, TrainConfig
 from repro.core.partition import PartitionPlan
+from repro.launch.mesh import make_mesh_from_config
 from repro.models import transformer as T
 from repro.optim import adamw
 
@@ -30,14 +30,6 @@ class TrainState(NamedTuple):
     params: Any
     opt: adamw.AdamWState
     step: jnp.ndarray
-
-
-def build_mesh(mesh_cfg: MeshConfig) -> Mesh:
-    devs = np.array(jax.devices())
-    need = mesh_cfg.num_devices
-    assert devs.size >= need, (devs.size, need)
-    return jax.make_mesh(mesh_cfg.shape, mesh_cfg.axes,
-                         devices=devs[:need].tolist())
 
 
 def make_step_fn(cfg: ModelConfig, tc: TrainConfig,
@@ -90,7 +82,8 @@ class Trainer:
                  mesh: Optional[Mesh] = None):
         self.cfg, self.shape, self.tc, self.plan = cfg, shape, tc, plan
         self.mesh_cfg = mesh_cfg
-        self.mesh = mesh if mesh is not None else build_mesh(mesh_cfg)
+        self.mesh = (mesh if mesh is not None
+                     else make_mesh_from_config(mesh_cfg))
         self.tp = mesh_cfg.tp
 
         pshape = jax.eval_shape(partial(T.model_init, cfg=cfg, tp=self.tp),

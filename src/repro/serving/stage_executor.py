@@ -32,7 +32,7 @@ from typing import Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.partition import PartitionPlan
 from repro.core.pipeline import (lm_two_stage_fns, pipeline_apply,
@@ -91,8 +91,21 @@ class StageAxisEngine:
         self.mesh = Mesh(np.array(jax.devices()[:num_stages]), ("stage",))
         s0, s1, _ = lm_two_stage_fns(cfg, self.plan, tp)
         self._fns = (s0, s1)
+        # stage s's segment lives on device s from here on, placed leaf
+        # by leaf; the embedding table is replicated so stage 0 embeds
+        # locally.  Both are call arguments, never constants captured by
+        # the jitted step
+        stage_sharding = NamedSharding(self.mesh, P("stage"))
+
+        def place(a, b):
+            return jax.make_array_from_single_device_arrays(
+                (num_stages,) + a.shape, stage_sharding,
+                [jax.device_put(x[None], d)
+                 for x, d in zip((a, b), self.mesh.devices)])
         self._stacked = split_lm_params_for_stages(params, cfg, self.plan,
-                                                   period)
+                                                   period, combine=place)
+        self._embed = jax.device_put(params["embed"],
+                                     NamedSharding(self.mesh, P()))
         self._emb_dtype = self.plan.embed_policy.precision.compute_dtype
         self._decode = jax.jit(self._decode_impl)
 
@@ -105,16 +118,17 @@ class StageAxisEngine:
     # ------------------------------------------------------------------
     # pipelined forward: one token per active slot per call
     # ------------------------------------------------------------------
-    def _decode_impl(self, tokens, lengths, temps, topks, seeds, steps):
+    def _decode_impl(self, embed_table, stacked, tokens, lengths, temps,
+                     topks, seeds, steps):
         """tokens [n_micro, S] int32 (right-padded — causality makes the
         pad positions invisible to the last real logit); lengths
         [n_micro].  Each slot is one microbatch of the stage pipeline.
         Returns [n_micro] int32 next tokens."""
         S = tokens.shape[1]
-        x = embed(self.params["embed"], tokens, self._emb_dtype)
+        x = embed(embed_table, tokens, self._emb_dtype)
         xs = x[:, None]                       # [n_micro, 1, S, d]
         outs = pipeline_apply(
-            self.mesh, "stage", self._fns, self._stacked, xs,
+            self.mesh, "stage", self._fns, stacked, xs,
             hidden_shape=(1, S, self.cfg.d_model),
             out_shape=(1, S, self.cfg.vocab_size),
             hidden_dtype=jnp.bfloat16, out_dtype=jnp.float32)
@@ -179,7 +193,8 @@ class StageAxisEngine:
             temps[i], topks[i] = sp.temperature, sp.top_k
             seeds[i], steps[i] = sp.seed, len(s.gen)
         t0 = time.perf_counter()
-        nxt = np.asarray(self._decode(jnp.asarray(tokens),
+        nxt = np.asarray(self._decode(self._embed, self._stacked,
+                                      jnp.asarray(tokens),
                                       jnp.asarray(lengths),
                                       jnp.asarray(temps),
                                       jnp.asarray(topks),

@@ -41,7 +41,9 @@ def test_two_stage_pipeline_matches_monolithic():
             Segment("backbone", 0, 2, PrecisionPolicy.bf16()),
             Segment("head", 2, 4, PrecisionPolicy.bf16())))
 
-        mesh = jax.make_mesh((2, 4), ("stage", "model"))
+        from repro.launch.mesh import make_mesh
+
+        mesh = make_mesh((2, 4), ("stage", "model"))
         s0, s1, _ = lm_two_stage_fns(cfg, plan)
         sp = split_lm_params_for_stages(params, cfg, plan, 1)
 
@@ -69,15 +71,10 @@ def test_compressed_grad_mean_close_to_exact():
         from jax.sharding import PartitionSpec as P
         from repro.optim.compression import compressed_grad_mean, CHUNK
 
-        # jax.shard_map (check_vma=) is the renamed
-        # jax.experimental.shard_map.shard_map (check_rep=)
-        if hasattr(jax, "shard_map"):
-            shard_map = partial(jax.shard_map, check_vma=False)
-        else:
-            from jax.experimental.shard_map import shard_map
-            shard_map = partial(shard_map, check_rep=False)
+        from repro.launch.mesh import make_mesh
 
-        mesh = jax.make_mesh((8,), ("pod",))
+        shard_map = partial(jax.shard_map, check_vma=False)
+        mesh = make_mesh((8,), ("pod",))
         grads = {"w": jax.random.normal(jax.random.PRNGKey(0),
                                         (8, CHUNK * 2)),
                  "b": jax.random.normal(jax.random.PRNGKey(1), (8, 4))}
