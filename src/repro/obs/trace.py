@@ -18,8 +18,8 @@ two timelines nest coherently in one view.
 Design constraints, in order:
 
 1. **Zero overhead off.**  ``enabled`` is False by default and every
-   recording method returns immediately; the engines' ``on_stage`` hook
-   is only installed while a traced batch runs.
+   recording method returns immediately; the tracer's relay sits on the
+   engines' ``on_stage`` hook only while a traced batch runs.
 2. **No orphan spans.**  Every terminal event (completion, rejection,
    drop, eviction) closes the request's open spans through
    :meth:`end_request`; ``open_spans()`` after a drained run is the

@@ -317,6 +317,76 @@ def _paste_block_rows(caches, kv, rows):
     return out
 
 
+class StageSpan:
+    """One timed phase of a serving step, reported when it completes to
+    a stage hook ``hook(stage, t0, t1, rids, attrs)`` with wall
+    ``perf_counter`` endpoints, and annotated in the profiler trace
+    (``label``, by default ``engine.<stage>``) while it runs.
+
+    ``t0`` starts the span at a stamp the caller already holds, so that
+    consecutive phases tile a step with nothing between them; ``end(t)``
+    ends it at a stamp the caller takes for its own counters, so that a
+    counter and its span agree exactly.  A span left by an exception
+    reports nothing: its phase did not complete."""
+
+    __slots__ = ("hook", "stage", "rids", "attrs", "label", "t0", "t1",
+                 "_annotation")
+
+    def __init__(self, hook, stage: str, rids, attrs: Dict,
+                 t0: Optional[float], label: Optional[str]):
+        self.hook, self.stage, self.rids, self.attrs = hook, stage, rids, attrs
+        self.label = label or f"engine.{stage}"
+        self.t0, self.t1 = t0, None
+
+    def __enter__(self) -> "StageSpan":
+        self._annotation = jax.profiler.TraceAnnotation(self.label)
+        self._annotation.__enter__()
+        if self.t0 is None:
+            self.t0 = time.perf_counter()
+        return self
+
+    def end(self, t: float) -> float:
+        self.t1 = t
+        return t
+
+    def __exit__(self, kind, value, tb) -> bool:
+        if self.t1 is None:
+            self.t1 = time.perf_counter()
+        self._annotation.__exit__(kind, value, tb)
+        if kind is None:
+            self.hook(self.stage, self.t0, self.t1, list(self.rids),
+                      self.attrs)
+        return False
+
+
+class _NoSpan:
+    """The span of an unhooked server: stamps and annotates nothing."""
+    t0 = t1 = None
+
+    def __enter__(self) -> "_NoSpan":
+        return self
+
+    def __exit__(self, kind, value, tb) -> bool:
+        return False
+
+    @staticmethod
+    def end(t: float) -> float:
+        return t
+
+
+_NO_SPAN = _NoSpan()
+
+
+def stage_span(hook, stage: str, rids=(), *, t0: Optional[float] = None,
+               label: Optional[str] = None, **attrs):
+    """``with stage_span(server.on_stage, "decode_sync", step=n):`` times
+    the block as a :class:`StageSpan` when ``hook`` is set; with no hook
+    it costs the None check and nothing else."""
+    if hook is None:
+        return _NO_SPAN
+    return StageSpan(hook, stage, rids, attrs, t0, label)
+
+
 class ContinuousBatchingEngine:
     """Slot-based continuous-batching decode over a paged KV pool.
 
@@ -414,10 +484,13 @@ class ContinuousBatchingEngine:
         # seam owner re-runs the handoff instead (restore_import)
         self.external_restore = False
         self.on_token: Optional[Callable[[int, int], None]] = None
-        # flight-recorder hook: ``on_stage(stage, t0, t1, rids, attrs)``
-        # with wall perf_counter endpoints; installed by EngineExecutor
-        # only while a traced batch runs, so the normal hot path pays a
-        # single None check per device call
+        # stage hook: ``on_stage(stage, t0, t1, rids, attrs)`` with wall
+        # perf_counter endpoints, one call per completed phase
+        # (``_span``) or point event (``_stage``).  Any subscriber may
+        # set it (the executor's tracer relay chains to the one it
+        # finds); while it is set every phase is also annotated in the
+        # profiler trace and decode steps wait on the device apart from
+        # the copy back.  Unset, the hot path pays a None check per phase
         self.on_stage = None
         # per-slot sampling knobs, threaded through the jit boundary.
         # Device copies are refreshed per admission round (the only
@@ -681,7 +754,12 @@ class ContinuousBatchingEngine:
         if self.on_token is not None:
             self.on_token(rid, tok)
 
+    def _span(self, stage: str, rids=(), **kw):
+        """A timed phase (``with self._span("decode_sync", step=n):``)."""
+        return stage_span(self.on_stage, stage, rids, **kw)
+
     def _stage(self, stage: str, t0: float, t1: float, rids, **attrs):
+        """A point event (``t0 == t1``) for the stage hook."""
         if self.on_stage is not None:
             self.on_stage(stage, t0, t1, list(rids), attrs)
 
@@ -940,32 +1018,31 @@ class ContinuousBatchingEngine:
         Outputs are recomputed and discarded — nothing re-emits."""
         g = len(gen)
         t0 = time.perf_counter()
-        if g > 1:
-            mask_tbl = -np.ones_like(self.table)
-            mask_tbl[i] = self.table[i]
-            mask_len = np.zeros_like(self.lengths)
-            mask_len[i] = padded_len
-            self._push_tables(mask_tbl, mask_len)
-            last = np.zeros((self.max_slots, 1), np.int32)
-            for j in range(g - 1):
-                last[i, 0] = gen[j]
-                if self.harden:      # reuse the compiled hardened program
-                    _, self.caches, _ = self._decode_h(
-                        self.params, jnp.asarray(last), self.caches)
-                else:
-                    _, self.caches = self._decode(
-                        self.params, jnp.asarray(last), self.caches)
-        self.admit_s += time.perf_counter() - t0
-        self.lengths[i] = padded_len + g - 1
-        self._gen_counts[i] = g
-        self.slots[i] = _Slot(req, list(gen), req.max_new - g,
-                              sampled=not sp.greedy)
-        self.last[i, 0] = gen[-1]
-        self._dirty = True
-        self._seal_rows(self.table[i][:self.lengths[i] // self.block_size])
-        t1 = time.perf_counter()
-        self._stage("replay", t0, t1, [req.rid], tokens=g,
-                    slot=i)
+        with self._span("replay", [req.rid], t0=t0, tokens=g, slot=i):
+            if g > 1:
+                mask_tbl = -np.ones_like(self.table)
+                mask_tbl[i] = self.table[i]
+                mask_len = np.zeros_like(self.lengths)
+                mask_len[i] = padded_len
+                self._push_tables(mask_tbl, mask_len)
+                last = np.zeros((self.max_slots, 1), np.int32)
+                for j in range(g - 1):
+                    last[i, 0] = gen[j]
+                    if self.harden:  # reuse the compiled hardened program
+                        _, self.caches, _ = self._decode_h(
+                            self.params, jnp.asarray(last), self.caches)
+                    else:
+                        _, self.caches = self._decode(
+                            self.params, jnp.asarray(last), self.caches)
+            self.admit_s += time.perf_counter() - t0
+            self.lengths[i] = padded_len + g - 1
+            self._gen_counts[i] = g
+            self.slots[i] = _Slot(req, list(gen), req.max_new - g,
+                                  sampled=not sp.greedy)
+            self.last[i, 0] = gen[-1]
+            self._dirty = True
+            self._seal_rows(
+                self.table[i][:self.lengths[i] // self.block_size])
 
     def restore_import(self, req: Request, gen: List[int],
                        handoff: "PrefillHandoff") -> None:
@@ -1019,6 +1096,10 @@ class ContinuousBatchingEngine:
     def _admit(self) -> List[Request]:
         admits: List[tuple] = []
         completed: List[Request] = []
+        # the bucket round's admit_plan phase starts here, or after the
+        # round's last chunked admission, which times its own chunks
+        hooked = self.on_stage is not None
+        plan_t0 = time.perf_counter() if hooked else None
         for i in range(self.max_slots):
             if not self.queue:
                 break
@@ -1030,6 +1111,8 @@ class ContinuousBatchingEngine:
                 # chunk call at a time (shares prefix blocks when the
                 # content-hash index has them live)
                 res = self._admit_chunked(i, req)
+                if hooked:
+                    plan_t0 = time.perf_counter()
                 if res is _DEFER:
                     self.deferrals += 1
                     break
@@ -1047,36 +1130,37 @@ class ContinuousBatchingEngine:
             admits.append((i, self.queue.pop(0)))
         if not admits:
             return completed
-        self._push_tables()                # freed + freshly-planned rows
-        self._dirty = False
-        # every admission this round rides one fused prefill+paste call;
-        # each admitted request occupies its slot's batch row, dead rows
-        # keep the compiled shape fixed
-        toks = np.zeros((self.max_slots, self.prompt_len), np.int32)
-        admit = np.zeros(self.max_slots, bool)
-        any_sampled = False
-        for i, req in admits:
-            toks[i, -req.prompt.shape[0]:] = req.prompt      # left-pad
-            admit[i] = True
-            sp = req.sampling or GREEDY
-            self._temps[i] = sp.temperature
-            self._topks[i] = sp.top_k
-            self._seeds[i] = sp.seed
-            any_sampled |= not sp.greedy
-        self._knobs_dev = (jnp.asarray(self._temps),
-                           jnp.asarray(self._topks),
-                           jnp.asarray(self._seeds))
-        temps_d, topks_d, seeds_d = self._knobs_dev
-        t0 = time.perf_counter()
-        firsts, self.caches = self._admit_step(
-            self.params, jnp.asarray(toks), self._prefill_cache,
-            self.caches, jnp.asarray(admit), temps_d, topks_d, seeds_d,
-            any_sampled)
-        firsts = np.asarray(firsts)
-        t1 = time.perf_counter()
+        with self._span("admit_plan", t0=plan_t0) as ph:
+            self._push_tables()            # freed + freshly-planned rows
+            self._dirty = False
+            # every admission this round rides one fused prefill+paste
+            # call; each admitted request occupies its slot's batch row,
+            # dead rows keep the compiled shape fixed
+            toks = np.zeros((self.max_slots, self.prompt_len), np.int32)
+            admit = np.zeros(self.max_slots, bool)
+            any_sampled = False
+            for i, req in admits:
+                toks[i, -req.prompt.shape[0]:] = req.prompt  # left-pad
+                admit[i] = True
+                sp = req.sampling or GREEDY
+                self._temps[i] = sp.temperature
+                self._topks[i] = sp.top_k
+                self._seeds[i] = sp.seed
+                any_sampled |= not sp.greedy
+            self._knobs_dev = (jnp.asarray(self._temps),
+                               jnp.asarray(self._topks),
+                               jnp.asarray(self._seeds))
+            temps_d, topks_d, seeds_d = self._knobs_dev
+            t0 = ph.end(time.perf_counter())
+        with self._span("admit", [req.rid for _, req in admits], t0=t0,
+                        tokens=self.prompt_len * len(admits)) as ph:
+            firsts, self.caches = self._admit_step(
+                self.params, jnp.asarray(toks), self._prefill_cache,
+                self.caches, jnp.asarray(admit), temps_d, topks_d, seeds_d,
+                any_sampled)
+            firsts = np.asarray(firsts)
+            t1 = ph.end(time.perf_counter())
         self.admit_s += t1 - t0
-        self._stage("admit", t0, t1, [req.rid for _, req in admits],
-                    tokens=self.prompt_len * len(admits))
         seal: List[int] = []
         for i, req in admits:
             self.lengths[i] = self.prompt_len
@@ -1107,27 +1191,29 @@ class ContinuousBatchingEngine:
     def _run_chunks(self, i: int, padded: np.ndarray, first_chunk: int,
                     sp: SamplingParams, rid: Optional[int] = None) -> int:
         """Drive the jitted chunk program over ``padded``'s chunks from
-        ``first_chunk`` on; returns the final chunk's sampled token."""
+        ``first_chunk`` on; returns the final chunk's sampled token.
+        The last chunk's stage and ``admit_s`` end with that token on
+        the host, as the bucket ``admit`` does."""
         c = self.prefill_chunk
         temps1 = jnp.asarray([sp.temperature], jnp.float32)
         topks1 = jnp.asarray([sp.top_k], jnp.int32)
         seeds1 = jnp.asarray([sp.seed], jnp.int32)
-        t0 = time.perf_counter()
-        firsts = None
-        ct0 = t0
-        for ci in range(first_chunk, padded.shape[0] // c):
-            firsts, self.caches = self._chunk_step(
-                self.params, jnp.asarray(padded[ci * c:(ci + 1) * c][None]),
-                self.caches, np.int32(i), np.int32(ci * c),
-                temps1, topks1, seeds1, not sp.greedy)
-            if self.on_stage is not None:
-                ct1 = time.perf_counter()
-                self._stage("prefill_chunk", ct0, ct1, [rid],
-                            chunk=ci, tokens=c)
-                ct0 = ct1
+        last = padded.shape[0] // c - 1
+        t0 = ct0 = time.perf_counter()
+        for ci in range(first_chunk, last + 1):
+            with self._span("prefill_chunk", [rid], t0=ct0, chunk=ci,
+                            tokens=c) as ph:
+                firsts, self.caches = self._chunk_step(
+                    self.params,
+                    jnp.asarray(padded[ci * c:(ci + 1) * c][None]),
+                    self.caches, np.int32(i), np.int32(ci * c),
+                    temps1, topks1, seeds1, not sp.greedy)
+                if ci == last:
+                    tok = int(np.asarray(firsts)[0])
+            ct0 = ph.t1
         self.admit_s += time.perf_counter() - t0
         self.prefill_tokens += padded.shape[0] - first_chunk * c
-        return int(np.asarray(firsts)[0])
+        return tok
 
     def _admit_chunked(self, i: int, req: Request):
         """Admit one over-bucket prompt into slot ``i`` via chunked
@@ -1213,12 +1299,37 @@ class ContinuousBatchingEngine:
                   if s is not None and i not in self.stalled]
         if not active:
             return []
-        if self._dirty:
-            self._push_tables()
-            self._dirty = False
-        any_sampled = any(s is not None and s.sampled for s in self.slots)
-        t0 = time.perf_counter()
-        sums_np: Optional[np.ndarray] = None
+        n = self.decode_steps
+        # phases tile the step, each starting where the last one ended;
+        # decode_step runs from the dispatch to the tokens on the host
+        with self._span("decode_prepare", step=n) as ph:
+            if self._dirty:
+                self._push_tables()
+                self._dirty = False
+            any_sampled = any(s is not None and s.sampled
+                              for s in self.slots)
+            t0 = ph.end(time.perf_counter())
+        with self._span("decode_step",
+                        [self.slots[i].req.rid for i in active], t0=t0,
+                        step=n, tokens=len(active)) as whole:
+            with self._span("decode_dispatch", t0=t0, step=n) as ph:
+                nxt, sums = self._dispatch_decode(any_sampled)
+            if self.on_stage is not None:
+                # traced steps only: the wait for the device, apart from
+                # the copy of its tokens back
+                with self._span("decode_sync", t0=ph.t1, step=n) as ph:
+                    nxt.block_until_ready()
+            with self._span("decode_fetch", t0=ph.t1, step=n) as ph:
+                sums_np = None if sums is None else np.asarray(sums)
+                nxt = np.asarray(nxt)
+                t1 = whole.end(ph.end(time.perf_counter()))
+        self.decode_s += t1 - t0
+        with self._span("decode_bookkeep", t0=t1, step=n):
+            return self._bookkeep_decode(active, nxt, sums_np)
+
+    def _dispatch_decode(self, any_sampled: bool):
+        """Launch one decode step; returns its device tokens and, when
+        hardened, the pool's per-block checksums (else None)."""
         if self.harden:
             # hardened dispatch: the same decode plus a per-block
             # checksum of the whole pool, fused — detection lands the
@@ -1233,8 +1344,8 @@ class ContinuousBatchingEngine:
             else:
                 nxt, self.caches, sums = self._decode_h(
                     self.params, jnp.asarray(self.last), self.caches)
-            sums_np = np.asarray(sums)
-        elif any_sampled:
+            return nxt, sums
+        if any_sampled:
             temps_d, topks_d, seeds_d = self._knobs_dev
             nxt, self.caches = self._decode_with(
                 self.params, jnp.asarray(self.last), self.caches,
@@ -1242,12 +1353,12 @@ class ContinuousBatchingEngine:
         else:
             nxt, self.caches = self._decode(
                 self.params, jnp.asarray(self.last), self.caches)
-        nxt = np.asarray(nxt)
-        t1 = time.perf_counter()
-        self.decode_s += t1 - t0
-        self._stage("decode_step", t0, t1,
-                    [self.slots[i].req.rid for i in active],
-                    step=self.decode_steps, tokens=len(active))
+        return nxt, None
+
+    def _bookkeep_decode(self, active: List[int], nxt: np.ndarray,
+                         sums_np: Optional[np.ndarray]) -> List[Request]:
+        """Host work after a decode step's tokens arrive: verify seals,
+        emit each token, finalize, evict and count."""
         bad_slots: set = set()
         if sums_np is not None and len(self.digests):
             # every sealed block is verified every step — the full-pool
@@ -1342,16 +1453,16 @@ class ContinuousBatchingEngine:
             self.total_tokens += 1
             self._emit(req.rid, tok)
         rows = self.table[i][:length // bs].copy()
-        g0 = time.perf_counter()
-        kv = _gather_block_rows(self.caches, jnp.asarray(rows))
-        digests = None
-        if self.harden:
-            # stamp the payload's per-block checksums before the blocks
-            # free — the importer verifies the paste against them
-            digests = tuple(int(s) for s in
-                            self._row_checksums([int(b) for b in rows]))
-        self._stage("handoff", g0, time.perf_counter(), [req.rid],
-                    blocks=len(rows), tokens=length)
+        with self._span("handoff", [req.rid], blocks=len(rows),
+                        tokens=length):
+            kv = _gather_block_rows(self.caches, jnp.asarray(rows))
+            digests = None
+            if self.harden:
+                # stamp the payload's per-block checksums before the
+                # blocks free — the importer verifies the paste against
+                # them
+                digests = tuple(int(s) for s in
+                                self._row_checksums([int(b) for b in rows]))
         self.alloc.release(self.shared.release(rows))
         self.table[i] = -1
         self.lengths[i] = 0
@@ -1384,11 +1495,10 @@ class ContinuousBatchingEngine:
         need[i] = -(-(length + req.max_new) // bs)
         self.table = paging.plan_blocks(self.table, self.alloc, need)
         rows = self.table[i][:length // bs]
-        p0 = time.perf_counter()
-        self.caches = _paste_block_rows(self.caches, handoff.kv,
-                                        jnp.asarray(rows))
-        self._stage("import", p0, time.perf_counter(), [req.rid],
-                    blocks=len(rows), tokens=length)
+        with self._span("import", [req.rid], blocks=len(rows),
+                        tokens=length):
+            self.caches = _paste_block_rows(self.caches, handoff.kv,
+                                            jnp.asarray(rows))
         self._verify_import(i, req, handoff, rows)
         self.lengths[i] = length
         self._gen_counts[i] = 1
@@ -1693,7 +1803,8 @@ class CoProcServer:
             eng.on_token = fn              # everything after
 
     # --- stage relay: engine stage names are disjoint (prefill:
-    # admit/prefill_chunk/handoff; decode: import/decode_step), and each
+    # admit/prefill_chunk/handoff; decode: import, decode_step and its
+    # decode_* phases), and each
     # decode shard tags its spans with its index so the fan-out is
     # visible per consumer -----------------------------------------------
     @property

@@ -116,18 +116,22 @@ class EngineExecutor:
                 dict(getattr(s, "imports_by_shard", {}) or {}))
 
     def _install_stage_relay(self, plan: ScheduledPlan, now: float,
-                             wall0: float) -> bool:
+                             wall0: float, found) -> bool:
         """While this batch runs, forward the engine's ``on_stage``
         events into the tracer, anchoring wall perf_counter times at the
         batch's *virtual* launch instant ``now`` — engine sub-spans
         (chunks, decode steps) then nest inside the pool's virtual
         ``serve`` span.  Per-request stages (prefill chunks, the
         handoff, the import) record under their rid; batch-wide stages
-        (fused admit, decode steps) are pool-lane spans (rid=None).
+        (fused admit, decode steps) and rid-less phases and events are
+        pool-lane spans (rid=None).  The executor's own ``batch`` stage
+        is not forwarded: the tracer has the batch as ``serve``.
         Disaggregated pools put prefill-side stages on the prefill stage
         pool's lane and stamp each chunk's energy at the discounted
         per-token rate, so summed chunk energy equals the stage
-        counters' charge exactly."""
+        counters' charge exactly.  Every event also reaches ``found``,
+        the hook the server had, which ``run`` puts back after the
+        batch."""
         tr = self.tracer
         if (tr is None or not tr.enabled
                 or not hasattr(self.server, "on_stage")):
@@ -138,11 +142,12 @@ class EngineExecutor:
                    if self.prefill_counters is not None else None)
 
         def relay(stage, w0, w1, rids, attrs):
+            if found is not None:
+                found(stage, w0, w1, rids, attrs)
+            if stage == "batch":
+                return
             vt0, vt1 = now + (w0 - wall0), now + (w1 - wall0)
-            if stage in ("admit", "decode_step",
-                         "seu_bitflip", "bitflip_detected"):
-                # batch-wide (or block-level, rid-less) events live on
-                # the pool lane
+            if not rids or stage in ("admit", "decode_step"):
                 tr.add(None, stage, vt0, vt1, pool=decode_pool,
                        rids=len(rids), **attrs)
                 return
@@ -158,9 +163,36 @@ class EngineExecutor:
 
     def run(self, plan: ScheduledPlan, requests: Sequence[RouterRequest],
             now: float = 0.0) -> Tuple[float, float]:
-        from repro.runtime.serve import Request as ServeRequest
+        """Serve ``requests`` to completion; returns the batch's wall
+        seconds and its energy.  The batch is one ``batch`` stage on the
+        server's stage hook, from this call to the batch's end."""
+        from repro.runtime.serve import stage_span
         t0 = time.perf_counter()
-        traced = self._install_stage_relay(plan, now, t0)
+        found = getattr(self.server, "on_stage", None)
+        traced = self._install_stage_relay(plan, now, t0, found)
+        try:
+            with stage_span(getattr(self.server, "on_stage", None),
+                            "batch", [r.rid for r in requests], t0=t0,
+                            label="executor.batch") as batch:
+                decoded = self._serve(plan, requests)
+                t1 = batch.end(time.perf_counter())
+        finally:
+            if traced:
+                self.server.on_stage = found
+        # Energy scales with tokens actually decoded this batch (every
+        # decode step is one forward pass priced at the plan's nominal
+        # per-inference energy_j) — not with request count, which would
+        # charge a max_new=16 request the same as a max_new=1 one and
+        # charge failover re-serves (zero decode) all over again.  The
+        # decode-only basis matches the pool's decode_tokens_per_s
+        # telemetry, so the orbit energy bucket drains against real work.
+        return t1 - t0, plan.energy_j * decoded
+
+    def _serve(self, plan: ScheduledPlan,
+               requests: Sequence[RouterRequest]) -> int:
+        """Submit the batch, step the server until it is done and charge
+        the pool counters; returns the tokens the server decoded."""
+        from repro.runtime.serve import Request as ServeRequest
         tok0, dec0, def0, pre0, adm0 = self._stats()
         h0 = self._hstats()
         px0, pl0, sh0 = self._sstats()
@@ -245,13 +277,4 @@ class EngineExecutor:
             pc.busy_s += adm1 - adm0
             pc.energy_j += (plan.energy_j * self.prefill_energy_scale
                             * (pre1 - pre0))
-        # Energy scales with tokens actually decoded this batch (every
-        # decode step is one forward pass priced at the plan's nominal
-        # per-inference energy_j) — not with request count, which would
-        # charge a max_new=16 request the same as a max_new=1 one and
-        # charge failover re-serves (zero decode) all over again.  The
-        # decode-only basis matches the pool's decode_tokens_per_s
-        # telemetry, so the orbit energy bucket drains against real work.
-        if traced:
-            self.server.on_stage = None
-        return time.perf_counter() - t0, plan.energy_j * (tok1 - tok0)
+        return tok1 - tok0
