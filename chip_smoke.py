@@ -106,11 +106,12 @@ def prompts(specs, vocab: int, seed: int):
 # kernel
 # ---------------------------------------------------------------------------
 def check_paged_kernel(batch: int, rows: int, page: int, kvp: int, gp: int,
-                       hd: int, mb: int, *, interpret: bool = False,
-                       seed: int = 0) -> float:
+                       hd: int, mb: int, *, layers: int = 3,
+                       interpret: bool = False, seed: int = 0) -> float:
     """The Pallas paged kernel against ``paged_attention_xla`` on random
-    bf16 pools and ragged lengths (one empty, one full).  Returns the
-    largest absolute difference."""
+    bf16 pools of ``layers`` layers, reading the middle one, with ragged
+    lengths (one empty, one full).  Returns the largest absolute
+    difference."""
     import jax.numpy as jnp
 
     from repro.kernels.ops import paged_attention_xla
@@ -122,14 +123,16 @@ def check_paged_kernel(batch: int, rows: int, page: int, kvp: int, gp: int,
         return jnp.asarray(rng.standard_normal(shape, np.float32),
                            jnp.bfloat16)
     q = normal(batch, kvp, gp, hd)
-    k_pool, v_pool = normal(rows, page, kvp, hd), normal(rows, page, kvp, hd)
+    k_pool = normal(layers, rows, page, kvp * hd)
+    v_pool = normal(layers, rows, page, kvp * hd)
     lengths = rng.integers(1, mb * page + 1, batch).astype(np.int32)
     lengths[0], lengths[-1] = 0, mb * page
     table = -np.ones((batch, mb), np.int32)
     for b in range(batch):                 # the last row is the trash row
         live = -(-int(lengths[b]) // page)
         table[b, :live] = rng.choice(rows - 1, live, replace=False)
-    args = (q, k_pool, v_pool, jnp.asarray(table), jnp.asarray(lengths))
+    args = (q, k_pool, v_pool, jnp.asarray(table), jnp.asarray(lengths),
+            jnp.int32(layers // 2))
     out = np.asarray(paged_attention_pallas(*args, interpret=interpret),
                      np.float32)
     ref = np.asarray(paged_attention_xla(*args), np.float32)
@@ -253,7 +256,9 @@ def serve_phase(arch: str = ARCH, smoke: bool = False, pool=None,
 
     # 1. the kernel at the pool's own shapes
     st = kv[0]
-    n_super, rows, page, kvp, hd = st.k_pool.shape
+    n_super, rows, page, width = st.k_pool.shape
+    kvp, hd = cfg.num_kv_heads, cfg.resolved_head_dim()
+    assert kvp * hd == width, (kvp, hd, width)
     gp = cfg.num_heads // cfg.num_kv_heads
     t0 = time.perf_counter()
     err = check_paged_kernel(engine.max_slots, rows, page, kvp, gp, hd,

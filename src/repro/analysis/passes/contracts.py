@@ -38,7 +38,7 @@ KERNEL_CONTRACTS: Dict[str, KernelContract] = {
         module="src/repro/kernels/paged_attention.py",
         kernel_fn="_paged_kernel",
         grid_rank=2,               # (sequence, table block); heads in-body
-        num_scalar_prefetch=2,     # block_table + lengths ride ahead
+        num_scalar_prefetch=3,     # block_table, lengths, layer ride ahead
         tail_guard=True,           # dead-block predication + init/finalize
         dimension_semantics=("parallel", "arbitrary"),
         divisibility_assert=False,  # pool rows are whole pages by layout

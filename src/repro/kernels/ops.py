@@ -78,22 +78,23 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 @jax.jit
 def paged_attention_xla(q: jnp.ndarray, k_pool: jnp.ndarray,
                         v_pool: jnp.ndarray, block_table: jnp.ndarray,
-                        lengths: jnp.ndarray) -> jnp.ndarray:
+                        lengths: jnp.ndarray, layer) -> jnp.ndarray:
     """XLA-native analogue of the Pallas paged-decode kernel: a scan over
     block-table columns with online softmax — one block per sequence in
     flight at a time, never the materialized [B, max_len] KV of
     ``gather_kv``.  This is the fast path on non-TPU backends, where the
     Pallas kernel would run under the (slow) interpreter."""
     b, kvp, gp, hd = q.shape
-    page = k_pool.shape[1]
+    page = k_pool.shape[2]
     mb = block_table.shape[1]
     qf = q.astype(jnp.float32) / jnp.sqrt(jnp.float32(hd))
 
     def step(carry, j):
         m, l, acc = carry
         rows = jnp.maximum(block_table[:, j], 0)
-        k = k_pool[rows].astype(jnp.float32)          # [B, P, KVp, hd]
-        v = v_pool[rows].astype(jnp.float32)
+        k = k_pool[layer, rows].reshape(b, page, kvp, hd)   # [B, P, KVp, hd]
+        v = v_pool[layer, rows].reshape(b, page, kvp, hd)
+        k, v = k.astype(jnp.float32), v.astype(jnp.float32)
         s = jnp.einsum("bkgd,bpkd->bkgp", qf, k)
         pos = j * page + jnp.arange(page)
         mask = pos[None, :] < lengths[:, None]        # [B, P]
@@ -121,17 +122,18 @@ def paged_attention_xla(q: jnp.ndarray, k_pool: jnp.ndarray,
 
 
 def paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray, v_pool: jnp.ndarray,
-                    block_table: jnp.ndarray,
-                    lengths: jnp.ndarray) -> jnp.ndarray:
+                    block_table: jnp.ndarray, lengths: jnp.ndarray,
+                    layer) -> jnp.ndarray:
     """One-token paged decode attention — walks the block table, never
     materializing a sequence's full KV.
 
     q: [B, KVp, gp, hd] (one query token per sequence, grouped-query
-    layout); k_pool/v_pool: [num_rows, P, KVp, hd] shared block pools;
-    block_table: [B, MB] int32; lengths: [B] int32.  Returns
-    [B, KVp, gp, hd].  See kernels/paged_attention.py for the layout
-    contract.  No shape padding here: head_layout already aligns KVp/gp
-    and the pool's P is the engine's block size.
+    layout); k_pool/v_pool: [L, num_rows, P, KVp * hd] shared block
+    pools of every layer; block_table: [B, MB] int32; lengths: [B]
+    int32; layer: the layer read.  Returns [B, KVp, gp, hd].  See
+    kernels/paged_attention.py for the layout contract.  No shape
+    padding here: head_layout already aligns KVp/gp and the pool's P is
+    the engine's block size.
 
     Backend selection differs from the other wrappers: on TPU the Pallas
     kernel runs compiled; elsewhere the serving path takes the XLA
@@ -141,14 +143,16 @@ def paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray, v_pool: jnp.ndarray,
     for a throughput benchmark).
     """
     if _interpret():
-        return paged_attention_xla(q, k_pool, v_pool, block_table, lengths)
-    return paged_attention_pallas(q, k_pool, v_pool, block_table, lengths)
+        return paged_attention_xla(q, k_pool, v_pool, block_table, lengths,
+                                   layer)
+    return paged_attention_pallas(q, k_pool, v_pool, block_table, lengths,
+                                  layer)
 
 
 @jax.jit
 def paged_chunk_attention_xla(q: jnp.ndarray, k_pool: jnp.ndarray,
                               v_pool: jnp.ndarray, table_row: jnp.ndarray,
-                              qpos: jnp.ndarray) -> jnp.ndarray:
+                              qpos: jnp.ndarray, layer) -> jnp.ndarray:
     """Chunk-prefill attention for ONE sequence against its paged KV.
 
     The chunked-paged-prefill companion to :func:`paged_attention_xla`:
@@ -159,22 +163,24 @@ def paged_chunk_attention_xla(q: jnp.ndarray, k_pool: jnp.ndarray,
     sequence's block-table row with an online softmax, one block in
     flight at a time.
 
-    q: [C, KVp, gp, hd]; k_pool/v_pool: [num_rows, P, KVp, hd];
+    q: [C, KVp, gp, hd]; k_pool/v_pool: [L, num_rows, P, KVp * hd];
     table_row: [MB] int32 (-1 = unallocated); qpos: [C] int32 absolute
-    positions of the chunk.  Returns [C, KVp, gp, hd].  Blocks past the
-    chunk (decode-budget rows, dead entries) fall to the causal mask:
-    their positions exceed every query position.
+    positions of the chunk; layer: the layer read.  Returns
+    [C, KVp, gp, hd].  Blocks past the chunk (decode-budget rows, dead
+    entries) fall to the causal mask: their positions exceed every query
+    position.
     """
     c, kvp, gp, hd = q.shape
-    page = k_pool.shape[1]
+    page = k_pool.shape[2]
     mb = table_row.shape[0]
     qf = q.astype(jnp.float32) / jnp.sqrt(jnp.float32(hd))
 
     def step(carry, j):
         m, l, acc = carry
         row = jnp.maximum(table_row[j], 0)
-        k = k_pool[row].astype(jnp.float32)           # [P, KVp, hd]
-        v = v_pool[row].astype(jnp.float32)
+        k = k_pool[layer, row].reshape(page, kvp, hd)  # [P, KVp, hd]
+        v = v_pool[layer, row].reshape(page, kvp, hd)
+        k, v = k.astype(jnp.float32), v.astype(jnp.float32)
         s = jnp.einsum("ckgd,pkd->kgcp", qf, k)
         pos = j * page + jnp.arange(page)
         mask = (pos[None, :] <= qpos[:, None]) & (table_row[j] >= 0)
@@ -196,13 +202,14 @@ def paged_chunk_attention_xla(q: jnp.ndarray, k_pool: jnp.ndarray,
 
 def paged_chunk_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                           v_pool: jnp.ndarray, table_row: jnp.ndarray,
-                          qpos: jnp.ndarray) -> jnp.ndarray:
+                          qpos: jnp.ndarray, layer) -> jnp.ndarray:
     """Backend front door for chunk-prefill paged attention (see
     :func:`paged_chunk_attention_xla`).  The block-walk runs as native
     XLA everywhere today; a Pallas grid over (q-block, kv-block) with
     the same scalar-prefetch table walk as the decode kernel is the
     drop-in TPU upgrade and slots in here."""
-    return paged_chunk_attention_xla(q, k_pool, v_pool, table_row, qpos)
+    return paged_chunk_attention_xla(q, k_pool, v_pool, table_row, qpos,
+                                     layer)
 
 
 def selective_scan(x, dt, b, c, a, d, bd: int = 512, q: int = 256):

@@ -14,10 +14,14 @@ O(blocks-touched) attend.
 
 Block-table layout contract (shared with ``runtime.paging``):
 
-  * ``k_pool``/``v_pool``: ``[num_rows, P, KVp, hd]`` — ``num_rows``
-    fixed-size rows of ``P`` token slots each.  Row ``num_rows - 1`` is
-    the *trash row*: never handed out by the allocator, it absorbs
-    writes for inactive batch slots and is never read by this kernel.
+  * ``k_pool``/``v_pool``: ``[L, num_rows, P, KVp * hd]`` — for each of
+    ``L`` layers, ``num_rows`` fixed-size rows of ``P`` token slots
+    each, heads merged into the minor axis (head ``h`` is lanes
+    ``h*hd .. (h+1)*hd``).  The kernel reads layer ``layer`` of the
+    stack in place: the model's layer scan carries the whole pool and
+    never slices a layer out of it.  Row ``num_rows - 1`` is the *trash
+    row*: never handed out by the allocator, it absorbs writes for
+    inactive batch slots and is never read by this kernel.
   * ``block_table``: ``[B, MB] int32`` — row ``b`` lists the pool rows
     of sequence ``b`` in token order; ``-1`` marks an unallocated entry.
     Tokens ``[j*P, (j+1)*P)`` of sequence ``b`` live in pool row
@@ -31,9 +35,11 @@ Grid is ``(B, MB)`` with the block sweep innermost and "arbitrary"
 semantics, so the online-softmax statistics (m, l, acc) stay
 VMEM-resident across a sequence's whole table walk — the decode
 analogue of `flash_attention.py`'s KV sweep.  Each grid step DMAs one
-whole pool row, ``(P, KVp, hd)``, and walks the KVp heads inside the
-body: a block's last two dims then equal the pool's, which is what the
-TPU's (8, 128) tiling rule asks of a block that is not tile-aligned.
+whole pool row, ``(1, 1, P, KVp * hd)`` at ``(layer, row, 0, 0)``, and
+walks the KVp heads inside the body with static lane slices: a block's
+last two dims then equal the pool's, which is what the TPU's (8, 128)
+tiling rule asks of a block that is not tile-aligned.  The layer index
+rides in as a third scalar-prefetch operand.
 """
 from __future__ import annotations
 
@@ -48,7 +54,7 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
+def _paged_kernel(tbl_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
                   m_ref, l_ref, acc_ref, *, page: int, num_blk: int,
                   kv_heads: int, scale: float):
     b_idx = pl.program_id(0)
@@ -67,13 +73,14 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     # row 0) but no head's matmuls are issued for it.
     @pl.when(j * page < seq_len)
     def _block():
-        gp = q_ref.shape[2]
+        gp, hd = q_ref.shape[2:]
         pos = j * page + jax.lax.broadcasted_iota(jnp.int32, (gp, page), 1)
         live = pos < seq_len
         for h in range(kv_heads):                 # static walk over heads
+            lanes = slice(h * hd, (h + 1) * hd)
             q = q_ref[0, h].astype(jnp.float32) * scale       # [gp, hd]
-            k = k_ref[0, :, h, :].astype(jnp.float32)         # [P, hd]
-            v = v_ref[0, :, h, :].astype(jnp.float32)
+            k = k_ref[0, 0, :, lanes].astype(jnp.float32)     # [P, hd]
+            v = v_ref[0, 0, :, lanes].astype(jnp.float32)
             s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
             s = jnp.where(live, s, NEG_INF)                   # [gp, P]
@@ -96,34 +103,36 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention_pallas(q: jnp.ndarray, k_pool: jnp.ndarray,
                            v_pool: jnp.ndarray, block_table: jnp.ndarray,
-                           lengths: jnp.ndarray,
+                           lengths: jnp.ndarray, layer,
                            interpret: bool = False) -> jnp.ndarray:
-    """One decode token of paged attention.
+    """One decode token of paged attention against layer ``layer``.
 
-    q: [B, KVp, gp, hd]; k_pool/v_pool: [num_rows, P, KVp, hd];
-    block_table: [B, MB] int32; lengths: [B] int32 -> [B, KVp, gp, hd].
+    q: [B, KVp, gp, hd]; k_pool/v_pool: [L, num_rows, P, KVp * hd];
+    block_table: [B, MB] int32; lengths: [B] int32; layer: int32
+    scalar -> [B, KVp, gp, hd].
     """
     b, kvp, gp, hd = q.shape
-    page = k_pool.shape[1]
+    n_layers, _, page, width = k_pool.shape
     mb = block_table.shape[1]
     scale = 1.0 / math.sqrt(hd)
     kernel = functools.partial(_paged_kernel, page=page, num_blk=mb,
                                kv_heads=kvp, scale=scale)
 
-    def q_map(i, j, tbl, lens):
+    def q_map(i, j, tbl, lens, lay):
         return (i, 0, 0, 0)
 
-    def kv_map(i, j, tbl, lens):
+    def kv_map(i, j, tbl, lens, lay):
         # dead entries (-1) clamp to row 0; the body is predicated off
-        return (jnp.maximum(tbl[i, j], 0), 0, 0, 0)
+        return (jnp.clip(lay[0], 0, n_layers - 1),
+                jnp.maximum(tbl[i, j], 0), 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, mb),
         in_specs=[
             pl.BlockSpec((1, kvp, gp, hd), q_map),
-            pl.BlockSpec((1, page, kvp, hd), kv_map),
-            pl.BlockSpec((1, page, kvp, hd), kv_map),
+            pl.BlockSpec((1, 1, page, width), kv_map),
+            pl.BlockSpec((1, 1, page, width), kv_map),
         ],
         out_specs=pl.BlockSpec((1, kvp, gp, hd), q_map),
         scratch_shapes=[
@@ -143,4 +152,5 @@ def paged_attention_pallas(q: jnp.ndarray, k_pool: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(block_table, lengths, q, k_pool, v_pool)
+    )(block_table, lengths, jnp.reshape(layer, (1,)).astype(jnp.int32),
+      q, k_pool, v_pool)

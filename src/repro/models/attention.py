@@ -247,10 +247,11 @@ def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int, tp: int = 1,
 def paged_decode_attention_apply(params: Dict, cfg: ModelConfig,
                                  x: jnp.ndarray,
                                  state: paging.PagedKVState,
-                                 positions: jnp.ndarray,
+                                 positions: jnp.ndarray, layer,
                                  policy: PrecisionPolicy = DEFAULT_POLICY
                                  ) -> Tuple[jnp.ndarray, paging.PagedKVState]:
-    """One-token decode step against a paged KV cache.  x: [B, 1, D].
+    """One-token decode step against layer ``layer`` of a paged KV
+    cache.  x: [B, 1, D].
 
     Unlike the dense path there is no single ``cache.pos``: every batch
     slot sits at its own context length, so the caller passes per-
@@ -263,11 +264,11 @@ def paged_decode_attention_apply(params: Dict, cfg: ModelConfig,
     b = x.shape[0]
     hd = cfg.resolved_head_dim()
     q, k, v = _project_qkv(params, cfg, x, positions, policy)
-    state = paging.append_tokens(state, k[:, 0], v[:, 0])
+    state = paging.append_tokens(state, k[:, 0], v[:, 0], layer)
     kvp = k.shape[2]
     qh = q[:, 0].reshape(b, kvp, -1, hd)          # grouped-query layout
     out = kops.paged_attention(qh, state.k_pool, state.v_pool,
-                               state.block_table, state.lengths)
+                               state.block_table, state.lengths, layer)
     out = out.reshape(b, 1, -1).astype(x.dtype)
     return pdot(out, params["wo"], policy), state
 
@@ -275,10 +276,11 @@ def paged_decode_attention_apply(params: Dict, cfg: ModelConfig,
 def paged_chunk_attention_apply(params: Dict, cfg: ModelConfig,
                                 x: jnp.ndarray,
                                 state: paging.PagedKVState,
-                                positions: jnp.ndarray, seq,
+                                positions: jnp.ndarray, seq, layer,
                                 policy: PrecisionPolicy = DEFAULT_POLICY
                                 ) -> Tuple[jnp.ndarray, paging.PagedKVState]:
-    """One prefill *chunk* of sequence ``seq`` against the paged cache.
+    """One prefill *chunk* of sequence ``seq`` against layer ``layer``
+    of the paged cache.
 
     x: [1, C, D]; positions: [1, C] absolute (chunk start must be
     block-aligned, C a whole number of blocks).  The chunk's KV is
@@ -292,11 +294,12 @@ def paged_chunk_attention_apply(params: Dict, cfg: ModelConfig,
     hd = cfg.resolved_head_dim()
     q, k, v = _project_qkv(params, cfg, x, positions, policy)
     state = paging.write_prefill_chunk(state, k[0], v[0], seq,
-                                       positions[0, 0])
+                                       positions[0, 0], layer)
     kvp = k.shape[2]
     qh = q[0].reshape(c, kvp, -1, hd)             # grouped-query layout
     out = kops.paged_chunk_attention(qh, state.k_pool, state.v_pool,
-                                     state.block_table[seq], positions[0])
+                                     state.block_table[seq], positions[0],
+                                     layer)
     out = out.reshape(b, c, -1).astype(x.dtype)
     return pdot(out, params["wo"], policy), state
 

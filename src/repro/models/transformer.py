@@ -131,10 +131,12 @@ def init_paged_decode_cache(cfg: ModelConfig, batch: int, num_blocks: int,
                             dtype=jnp.bfloat16):
     """Per-sublayer paged KV caches for continuous-batching decode.
 
-    Every attention sublayer gets its own block pool; table and lengths
-    are replicated per super-block so the stacked cache scans exactly
-    like the dense one (the engine keeps them in lockstep from its
-    host-side mirror).  Paged decode is attention-only for now — SSM /
+    Every attention sublayer gets its own block pool, ``[n_super, rows,
+    P, KVp * hd]`` (one layer per super-block); table and lengths are
+    replicated per super-block so they scan like a dense cache (the
+    engine keeps them in lockstep from its host-side mirror), while the
+    pools ride the layer scan whole (:func:`_paged_scan`).  Paged
+    decode is attention-only for now — SSM /
     RWKV recurrent states are tiny and per-slot already, but their
     prefill handoff into a mixed paged batch is future work (see
     ROADMAP "decode engine").
@@ -151,12 +153,15 @@ def init_paged_decode_cache(cfg: ModelConfig, batch: int, num_blocks: int,
     lay = attn.head_layout(cfg.num_heads, cfg.num_kv_heads, tp)
     hd = cfg.resolved_head_dim()
     n_super = cfg.num_layers // len(pat)
-    one = {f"sub_{j}": paging.init_paged_cache(
-               batch, num_blocks, block_size, lay.KVp, hd, dtype=dtype,
-               max_blocks=max_blocks)
-           for j in range(len(pat))}
-    return jax.tree_util.tree_map(
-        lambda x: jnp.broadcast_to(x[None], (n_super,) + x.shape), one)
+    stack = lambda x: jnp.broadcast_to(x[None], (n_super,) + x.shape)
+    caches = {}
+    for j in range(len(pat)):
+        st = paging.init_paged_cache(batch, num_blocks, block_size, lay.KVp,
+                                     hd, dtype=dtype, max_blocks=max_blocks,
+                                     layers=n_super)
+        caches[f"sub_{j}"] = st._replace(block_table=stack(st.block_table),
+                                         lengths=stack(st.lengths))
+    return caches
 
 
 def first_paged_state(cache) -> Optional[PagedKVState]:
@@ -174,11 +179,13 @@ def first_paged_state(cache) -> Optional[PagedKVState]:
 def _sublayer_apply(sub: Dict, cfg: ModelConfig, kind: str, is_moe: bool,
                     x: jnp.ndarray, positions: jnp.ndarray,
                     policy: PrecisionPolicy, tp: int,
-                    cache=None, decode: bool = False, chunk_seq=None):
+                    cache=None, decode: bool = False, chunk_seq=None,
+                    layer=None):
     """Returns (x, new_cache, aux).  ``chunk_seq`` (paged caches only)
     switches prefill into chunked-paged mode: the window is one chunk of
     sequence ``chunk_seq`` at absolute ``positions``, pasted into its
-    blocks and attended against the paged prefix."""
+    blocks and attended against the paged prefix.  A paged cache holds
+    every layer's pool; ``layer`` is the one this sublayer owns."""
     _, norm = make_norm("rmsnorm")
     aux = jnp.zeros((), jnp.float32)
     h = norm(sub["norm1"], x, cfg.norm_eps)
@@ -187,13 +194,14 @@ def _sublayer_apply(sub: Dict, cfg: ModelConfig, kind: str, is_moe: bool,
         if decode:
             if isinstance(cache, PagedKVState):
                 out, new_cache = attn.paged_decode_attention_apply(
-                    sub["mixer"], cfg, h, cache, positions, policy)
+                    sub["mixer"], cfg, h, cache, positions, layer, policy)
             else:
                 out, new_cache = attn.decode_attention_apply(
                     sub["mixer"], cfg, h, cache, policy)
         elif chunk_seq is not None and isinstance(cache, PagedKVState):
             out, new_cache = attn.paged_chunk_attention_apply(
-                sub["mixer"], cfg, h, cache, positions, chunk_seq, policy)
+                sub["mixer"], cfg, h, cache, positions, chunk_seq, layer,
+                policy)
         else:
             out = attn.attention_apply(sub["mixer"], cfg, h, positions, policy)
             if cache is not None:
@@ -278,18 +286,22 @@ def _prefill_kv(mix_params, cfg, h, positions, cache, policy):
 # ---------------------------------------------------------------------------
 def _segment_scan(seg_params, cfg: ModelConfig, x, positions,
                   policy: PrecisionPolicy, tp: int, caches=None,
-                  decode: bool = False, chunk_seq=None):
-    """Scan a segment's super-blocks.  Returns (x, new_caches, aux_sum)."""
+                  decode: bool = False, chunk_seq=None,
+                  layer_offset: int = 0):
+    """Scan a segment's super-blocks.  Returns (x, new_caches, aux_sum).
+
+    Paged caches hold the pools of the whole stack; this segment's
+    super-blocks own layers ``layer_offset ..`` of them."""
     pat = sublayer_pattern(cfg)
 
-    def block(x, blk_params, blk_cache):
+    def block(x, blk_params, blk_cache, layer=None):
         aux_tot = jnp.zeros((), jnp.float32)
         new_blk_cache = {} if blk_cache is not None else None
         for j, (kind, is_moe) in enumerate(pat):
             c = None if blk_cache is None else blk_cache[f"sub_{j}"]
             x, c2, aux = _sublayer_apply(blk_params[f"sub_{j}"], cfg, kind,
                                          is_moe, x, positions, policy, tp,
-                                         c, decode, chunk_seq)
+                                         c, decode, chunk_seq, layer)
             if new_blk_cache is not None:
                 new_blk_cache[f"sub_{j}"] = c2
             aux_tot = aux_tot + aux
@@ -303,6 +315,9 @@ def _segment_scan(seg_params, cfg: ModelConfig, x, positions,
         if cfg.remat_group and not cfg.scan_layers:
             # probes mimic the sqrt-remat double-recompute cost structure
             block = jax.checkpoint(block, static_argnums=())
+
+    if first_paged_state(caches) is not None:
+        return _paged_scan(block, cfg, x, seg_params, caches, layer_offset)
 
     if not cfg.scan_layers:          # unrolled (cost probes, tiny models)
         n = jax.tree_util.tree_leaves(seg_params)[0].shape[0]
@@ -355,8 +370,82 @@ def _segment_scan(seg_params, cfg: ModelConfig, x, positions,
     return x, new_caches, aux
 
 
+def _paged_scan(block, cfg: ModelConfig, x, seg_params, caches,
+                layer_offset: int):
+    """Scan a segment over paged caches, carrying every pool whole.
+
+    ``caches``: ``{sub_j: PagedKVState}`` whose pools hold every layer of
+    the stack and whose tables and lengths hold this segment's n
+    super-blocks.  Super-block i writes its new token rows into layer
+    ``layer_offset + i`` of each pool and its kernel reads that layer in
+    place.  The pools are the scan's carry, not its xs/ys: a ys buffer
+    cannot alias the xs it is read from, and slicing a layer out of the
+    pool copies it.  Returns (x, new_caches, aux_sum).
+    """
+    assert all(isinstance(st, PagedKVState) for st in caches.values()), \
+        "paged decode needs a paged cache for every sublayer"
+    n = jax.tree_util.tree_leaves(seg_params)[0].shape[0]
+
+    def body(carry, inp):
+        x, aux, pools = carry
+        blk_params, layer, tables = inp
+        blk_cache = {key: PagedKVState(*pools[key], *tables[key])
+                     for key in pools}
+        x, new, a = block(x, blk_params, blk_cache, layer)
+        return ((x, aux + a,
+                 {key: (st.k_pool, st.v_pool) for key, st in new.items()}),
+                {key: (st.block_table, st.lengths)
+                 for key, st in new.items()})
+
+    init = (x, jnp.zeros((), jnp.float32),
+            {key: (st.k_pool, st.v_pool) for key, st in caches.items()})
+    xs = (seg_params, layer_offset + jnp.arange(n, dtype=jnp.int32),
+          {key: (st.block_table, st.lengths) for key, st in caches.items()})
+    if cfg.scan_layers:
+        (x, aux, pools), tables = jax.lax.scan(body, init, xs)
+    else:                            # unrolled (cost probes, tiny models)
+        carry, outs = init, []
+        for i in range(n):
+            carry, out = body(carry,
+                              jax.tree_util.tree_map(lambda a: a[i], xs))
+            outs.append(out)
+        x, aux, pools = carry
+        tables = jax.tree_util.tree_map(lambda *ys: jnp.stack(ys), *outs)
+    return (x, {key: PagedKVState(*pools[key], *tables[key])
+                for key in pools}, aux)
+
+
 def _slice_stack(tree, lo: int, hi: int):
     return jax.tree_util.tree_map(lambda a: a[lo:hi], tree)
+
+
+def _is_paged(node) -> bool:
+    return isinstance(node, PagedKVState)
+
+
+def _segment_cache(cache, lo: int, hi: int):
+    """Super-blocks ``lo:hi`` of a cache tree.  Dense caches are sliced;
+    a paged cache keeps its pools whole (the segment indexes them at its
+    layer offset) and slices only its tables and lengths."""
+    def part(c):
+        if _is_paged(c):
+            return c._replace(block_table=c.block_table[lo:hi],
+                              lengths=c.lengths[lo:hi])
+        return c[lo:hi]
+    return jax.tree_util.tree_map(part, cache, is_leaf=_is_paged)
+
+
+def _join_segments(parts):
+    """Inverse of :func:`_segment_cache` over the segments in order.  A
+    paged pool comes from the last segment, which wrote after the
+    others into the same pool."""
+    def join(*cs):
+        if _is_paged(cs[0]):
+            return cs[-1]._replace(
+                block_table=jnp.concatenate([c.block_table for c in cs]),
+                lengths=jnp.concatenate([c.lengths for c in cs]))
+        return jnp.concatenate(cs, axis=0)
+    return jax.tree_util.tree_map(join, *parts, is_leaf=_is_paged)
 
 
 # ---------------------------------------------------------------------------
@@ -415,10 +504,16 @@ def forward(params: Dict, cfg: ModelConfig, tokens: jnp.ndarray,
     for seg in plan.segments:
         lo, hi = seg.start // period, seg.end // period
         seg_params = _slice_stack(params["layers"], lo, hi)
-        seg_cache = None if cache is None else _slice_stack(cache, lo, hi)
+        seg_cache = None if cache is None else _segment_cache(cache, lo, hi)
         x, seg_new, aux = _segment_scan(seg_params, cfg, x, positions,
                                         seg.policy, tp, seg_cache, decode,
-                                        chunk_seq)
+                                        chunk_seq, lo)
+        if cache is not None:
+            # the next segment writes into the pools this one left
+            cache = jax.tree_util.tree_map(
+                lambda c, n: (c._replace(k_pool=n.k_pool, v_pool=n.v_pool)
+                              if _is_paged(c) else c),
+                cache, seg_new, is_leaf=_is_paged)
         new_cache_parts.append(seg_new)
         aux_total = aux_total + aux
 
@@ -429,8 +524,7 @@ def forward(params: Dict, cfg: ModelConfig, tokens: jnp.ndarray,
 
     new_cache = None
     if cache is not None:
-        new_cache = jax.tree_util.tree_map(
-            lambda *parts: jnp.concatenate(parts, axis=0), *new_cache_parts)
+        new_cache = _join_segments(new_cache_parts)
     return LMOutput(logits, new_cache, aux_total)
 
 

@@ -17,7 +17,13 @@ Two attention paths read the paged cache:
     kernel grid (scalar-prefetch index maps), O(blocks-touched) traffic.
     This is what the continuous-batching engine serves with.
 
-Layout note: pools carry ``num_blocks + 1`` rows.  The last row is a
+Layout note: a pool is ``[L, num_blocks + 1, P, KVp * hd]`` — one
+stack of block rows per layer, the heads merged into the minor axis so
+that the TPU's default layout is row-major and unpadded, the layout the
+Pallas kernel reads.  Writers and readers take a ``layer`` index into
+the stack and reshape only their small per-token operands; the pool is
+never reshaped or sliced per layer (on a TPU either is a copy of it).
+Pools carry ``num_blocks + 1`` rows.  The last row is a
 *trash row* the allocator never hands out; ``append_tokens`` routes
 writes from batch slots with no allocated blocks (inactive slots of a
 fixed-size serving batch) into it so they can ride in the same scatter
@@ -45,7 +51,7 @@ class OutOfBlocksError(RuntimeError):
 
 
 class PagedKVState(NamedTuple):
-    k_pool: jnp.ndarray       # [num_blocks + 1, P, KVp, hd] (last = trash)
+    k_pool: jnp.ndarray       # [L, num_blocks + 1, P, KVp * hd] (last = trash)
     v_pool: jnp.ndarray
     block_table: jnp.ndarray  # [B, max_blocks] int32 (-1 = unallocated)
     lengths: jnp.ndarray      # [B] int32 tokens written per sequence
@@ -109,8 +115,10 @@ class BlockAllocator:
 def init_paged_cache(batch: int, num_blocks: int, block_size: int,
                      kv_heads: int, head_dim: int,
                      dtype=jnp.bfloat16,
-                     max_blocks: Optional[int] = None) -> PagedKVState:
-    """Pools get one extra trash row (see module docstring).
+                     max_blocks: Optional[int] = None,
+                     layers: int = 1) -> PagedKVState:
+    """Pools of ``layers`` layers, each with one extra trash row (see
+    module docstring).
 
     ``max_blocks`` bounds the per-sequence table width (defaults to
     ``num_blocks``: any sequence may own the whole pool).  The serving
@@ -119,9 +127,9 @@ def init_paged_cache(batch: int, num_blocks: int, block_size: int,
     """
     if max_blocks is None:
         max_blocks = num_blocks
+    shape = (layers, num_blocks + 1, block_size, kv_heads * head_dim)
     return PagedKVState(
-        jnp.zeros((num_blocks + 1, block_size, kv_heads, head_dim), dtype),
-        jnp.zeros((num_blocks + 1, block_size, kv_heads, head_dim), dtype),
+        jnp.zeros(shape, dtype), jnp.zeros(shape, dtype),
         -jnp.ones((batch, max_blocks), jnp.int32),
         jnp.zeros((batch,), jnp.int32))
 
@@ -162,7 +170,7 @@ def plan_blocks(table: np.ndarray, alloc: BlockAllocator,
 def ensure_blocks(state: PagedKVState, alloc: BlockAllocator,
                   new_tokens: np.ndarray) -> PagedKVState:
     """Grow each sequence's table to cover len+new tokens (atomic)."""
-    p = state.k_pool.shape[1]
+    p = state.k_pool.shape[2]
     lengths = np.asarray(state.lengths)
     need = -(-(lengths + np.asarray(new_tokens)) // p)   # blocks, not tokens
     table = plan_blocks(np.asarray(state.block_table), alloc, need)
@@ -180,34 +188,46 @@ def release_sequence(state: PagedKVState, alloc: BlockAllocator,
                           lengths=jnp.asarray(lengths))
 
 
+def _rows(x: jnp.ndarray, lead: int) -> jnp.ndarray:
+    """Merge a per-token operand's heads into the pool's minor axis:
+    ``[*lead dims, KVp, hd]`` -> ``[*lead dims, KVp * hd]``."""
+    return x.reshape(*x.shape[:lead], -1)
+
+
 @jax.jit
 def append_tokens(state: PagedKVState, k: jnp.ndarray,
-                  v: jnp.ndarray) -> PagedKVState:
-    """Write one new token per sequence.  k, v: [B, KVp, hd].
+                  v: jnp.ndarray, layer=0) -> PagedKVState:
+    """Write one new token per sequence into layer ``layer``.  k, v:
+    [B, KVp, hd].
 
     Sequences whose next block is unallocated (inactive slots of a
     fixed-size serving batch) write to the trash row instead and their
     length does not advance — the continuous-batching engine relies on
     this to run one fixed-shape scatter for a partially-occupied batch.
+    Only the B token rows are written: inside the model's layer scan the
+    pool is updated in place.
     """
-    p = state.k_pool.shape[1]
-    trash = state.k_pool.shape[0] - 1
+    p = state.k_pool.shape[2]
+    trash = state.k_pool.shape[1] - 1
     blk_idx = jnp.minimum(state.lengths // p, state.block_table.shape[1] - 1)
     blk = jnp.take_along_axis(state.block_table, blk_idx[:, None],
                               axis=1)[:, 0]                    # [B]
     active = blk >= 0
     blk = jnp.where(active, blk, trash)
     off = jnp.where(active, state.lengths % p, 0)
-    k_pool = state.k_pool.at[blk, off].set(k.astype(state.k_pool.dtype))
-    v_pool = state.v_pool.at[blk, off].set(v.astype(state.v_pool.dtype))
+    k_pool = state.k_pool.at[layer, blk, off].set(
+        _rows(k, 1).astype(state.k_pool.dtype))
+    v_pool = state.v_pool.at[layer, blk, off].set(
+        _rows(v, 1).astype(state.v_pool.dtype))
     return PagedKVState(k_pool, v_pool, state.block_table,
                         state.lengths + active.astype(jnp.int32))
 
 
 @jax.jit
 def write_prefill(state: PagedKVState, k: jnp.ndarray, v: jnp.ndarray,
-                  seq) -> PagedKVState:
-    """Paste a prefilled sequence's KV into its blocks in one scatter.
+                  seq, layer=0) -> PagedKVState:
+    """Paste a prefilled sequence's KV into its blocks of layer
+    ``layer`` in one scatter.
 
     k, v: [S, KVp, hd] — tokens 0..S-1 of sequence ``seq`` (whose table
     row must already cover ceil(S / P) blocks and whose length restarts
@@ -215,17 +235,17 @@ def write_prefill(state: PagedKVState, k: jnp.ndarray, v: jnp.ndarray,
     ``lengths[seq]`` so every reader masks it off, and subsequent
     ``append_tokens`` writes land on the exact slots anyway.
     """
-    p = state.k_pool.shape[1]
+    p = state.k_pool.shape[2]
     s = k.shape[0]
     nb = -(-s // p)
     pad = nb * p - s
 
     def blocked(x):
-        x = jnp.pad(x, ((0, pad), (0, 0), (0, 0)))
-        return x.reshape(nb, p, *x.shape[1:])
+        x = jnp.pad(_rows(x, 1), ((0, pad), (0, 0)))
+        return x.reshape(nb, p, -1).astype(state.k_pool.dtype)
     rows = jnp.maximum(state.block_table[seq, :nb], 0)
-    k_pool = state.k_pool.at[rows].set(blocked(k).astype(state.k_pool.dtype))
-    v_pool = state.v_pool.at[rows].set(blocked(v).astype(state.v_pool.dtype))
+    k_pool = state.k_pool.at[layer, rows].set(blocked(k))
+    v_pool = state.v_pool.at[layer, rows].set(blocked(v))
     return PagedKVState(k_pool, v_pool, state.block_table,
                         state.lengths.at[seq].set(s))
 
@@ -234,34 +254,36 @@ def write_prefill(state: PagedKVState, k: jnp.ndarray, v: jnp.ndarray,
 def write_prefill_batch(state: PagedKVState, k: jnp.ndarray,
                         v: jnp.ndarray, admit: jnp.ndarray) -> PagedKVState:
     """Batched :func:`write_prefill`: paste every admitted slot's prefill
-    KV in one scatter.
+    KV, every layer at once, in one scatter per pool.
 
-    k, v: [B, S, KVp, hd] — the whole prefill batch; ``admit``: [B] bool.
-    Non-admitted rows (occupied slots riding along in the fixed-shape
-    prefill bucket, or empty slots) scatter into the trash row; admitted
-    rows land in their table blocks and restart at length S.
+    k, v: [L, B, S, KVp, hd] — the whole prefill batch of each of the
+    pool's L layers; ``admit``: [B] bool.  Non-admitted rows (occupied
+    slots riding along in the fixed-shape prefill bucket, or empty
+    slots) scatter into the trash row; admitted rows land in their table
+    blocks and restart at length S.
     """
-    p = state.k_pool.shape[1]
-    trash = state.k_pool.shape[0] - 1
-    b, s = k.shape[:2]
+    p = state.k_pool.shape[2]
+    trash = state.k_pool.shape[1] - 1
+    n_layers, b, s = k.shape[:3]
     nb = -(-s // p)
     pad = nb * p - s
 
     def blocked(x):
-        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        return x.reshape(b * nb, p, *x.shape[2:])
+        x = jnp.pad(_rows(x, 3), ((0, 0), (0, 0), (0, pad), (0, 0)))
+        return x.reshape(n_layers, b * nb, p, -1).astype(state.k_pool.dtype)
     rows = state.block_table[:, :nb]
     rows = jnp.where(admit[:, None] & (rows >= 0), rows, trash).reshape(-1)
-    k_pool = state.k_pool.at[rows].set(blocked(k).astype(state.k_pool.dtype))
-    v_pool = state.v_pool.at[rows].set(blocked(v).astype(state.v_pool.dtype))
+    k_pool = state.k_pool.at[:, rows].set(blocked(k))
+    v_pool = state.v_pool.at[:, rows].set(blocked(v))
     return PagedKVState(k_pool, v_pool, state.block_table,
                         jnp.where(admit, s, state.lengths))
 
 
 @jax.jit
 def write_prefill_chunk(state: PagedKVState, k: jnp.ndarray, v: jnp.ndarray,
-                        seq, start) -> PagedKVState:
-    """Paste one *chunk* of a prefill into sequence ``seq``'s blocks.
+                        seq, start, layer=0) -> PagedKVState:
+    """Paste one *chunk* of a prefill into sequence ``seq``'s blocks of
+    layer ``layer``.
 
     k, v: [C, KVp, hd] — tokens ``start .. start+C-1`` of the sequence.
     Both ``start`` and ``C`` must be block-aligned (``% P == 0``), so the
@@ -274,17 +296,17 @@ def write_prefill_chunk(state: PagedKVState, k: jnp.ndarray, v: jnp.ndarray,
     chunk leaves the true prefill length behind.  Unallocated table
     entries route to the trash row (same contract as ``append_tokens``).
     """
-    p = state.k_pool.shape[1]
-    trash = state.k_pool.shape[0] - 1
+    p = state.k_pool.shape[2]
+    trash = state.k_pool.shape[1] - 1
     c = k.shape[0]
     nb = c // p
     row = jax.lax.dynamic_slice(state.block_table[seq], (start // p,), (nb,))
     rows = jnp.where(row >= 0, row, trash)
 
     def blocked(x):
-        return x.reshape(nb, p, *x.shape[1:])
-    k_pool = state.k_pool.at[rows].set(blocked(k).astype(state.k_pool.dtype))
-    v_pool = state.v_pool.at[rows].set(blocked(v).astype(state.v_pool.dtype))
+        return _rows(x, 1).reshape(nb, p, -1).astype(state.k_pool.dtype)
+    k_pool = state.k_pool.at[layer, rows].set(blocked(k))
+    v_pool = state.v_pool.at[layer, rows].set(blocked(v))
     return PagedKVState(k_pool, v_pool, state.block_table,
                         state.lengths.at[seq].set(start + c))
 
@@ -292,23 +314,20 @@ def write_prefill_chunk(state: PagedKVState, k: jnp.ndarray, v: jnp.ndarray,
 def block_checksums(state: PagedKVState, rows: jnp.ndarray) -> jnp.ndarray:
     """Integrity checksums for pool rows ``rows`` — jit-safe.
 
-    Bit-casts each block's K and V content to unsigned integers and sums
-    them mod 2**32, so any single-event upset (one flipped bit anywhere
-    in the block) changes the checksum.  Works on plain ``[NB+1, P, KVp,
-    hd]`` pools and on the engine's sublayer-stacked ``[S, NB+1, ...]``
-    pools; rows index the block axis either way.  Pass the trash row to
-    pad ``rows`` to a fixed width — its checksum comes back like any
-    other and callers just ignore it, keeping one compiled shape.
+    Bit-casts each block's K and V content, over every layer, to
+    unsigned integers and sums them mod 2**32, so any single-event upset
+    (one flipped bit anywhere in the block) changes the checksum.  Pass
+    the trash row to pad ``rows`` to a fixed width — its checksum comes
+    back like any other and callers just ignore it, keeping one compiled
+    shape.
     """
     total = None
     for pool in (state.k_pool, state.v_pool):
-        x = pool[:, rows] if pool.ndim == 5 else pool[rows]
         nbits = jnp.dtype(pool.dtype).itemsize * 8
         u = jax.lax.bitcast_convert_type(
-            x, jnp.uint16 if nbits == 16 else jnp.uint32).astype(jnp.uint32)
-        row_axis = 1 if pool.ndim == 5 else 0
-        s = jnp.sum(u, axis=tuple(a for a in range(u.ndim) if a != row_axis),
-                    dtype=jnp.uint32)
+            pool[:, rows],
+            jnp.uint16 if nbits == 16 else jnp.uint32).astype(jnp.uint32)
+        s = jnp.sum(u, axis=(0, 2, 3), dtype=jnp.uint32)
         total = s if total is None else total + s
     return total
 
@@ -329,13 +348,11 @@ def pool_checksums(state: PagedKVState) -> jnp.ndarray:
         nbits = jnp.dtype(pool.dtype).itemsize * 8
         u = jax.lax.bitcast_convert_type(
             pool, jnp.uint16 if nbits == 16 else jnp.uint32)
-        row_axis = 1 if pool.ndim == 5 else 0
         # 16-bit pools accumulate mod 2**16 — a single-event upset flips
         # one bit, shifting the sum by a nonzero power of two either
         # way, and the native-width accumulate skips the elementwise
         # upcast on the decode hot path
-        s = jnp.sum(u, axis=tuple(a for a in range(u.ndim) if a != row_axis),
-                    dtype=u.dtype).astype(jnp.uint32)
+        s = jnp.sum(u, axis=(0, 2, 3), dtype=u.dtype).astype(jnp.uint32)
         total = s if total is None else total + s
     return total
 
@@ -496,36 +513,39 @@ class SharedBlockIndex:
         self._by_digest.pop(self._digest_of.pop(b), None)
 
 
-def gather_kv(state: PagedKVState, max_len: int
+def gather_kv(state: PagedKVState, max_len: int, layer=0
               ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Materialize each sequence's KV up to max_len.
+    """Materialize each sequence's KV of layer ``layer`` up to max_len.
 
-    Returns (k [B, max_len, KVp, hd], v likewise, valid [B, max_len]).
+    Returns (k [B, max_len, KVp * hd], v likewise, valid [B, max_len]).
     """
-    p = state.k_pool.shape[1]
+    p = state.k_pool.shape[2]
     nb = -(-max_len // p)
     table = jnp.where(state.block_table[:, :nb] >= 0,
                       state.block_table[:, :nb], 0)
-    k = state.k_pool[table]                    # [B, nb, P, KVp, hd]
-    v = state.v_pool[table]
+    k = state.k_pool[layer][table]             # [B, nb, P, KVp * hd]
+    v = state.v_pool[layer][table]
     b = k.shape[0]
-    k = k.reshape(b, nb * p, *k.shape[3:])[:, :max_len]
-    v = v.reshape(b, nb * p, *v.shape[3:])[:, :max_len]
+    k = k.reshape(b, nb * p, -1)[:, :max_len]
+    v = v.reshape(b, nb * p, -1)[:, :max_len]
     valid = jnp.arange(max_len)[None, :] < state.lengths[:, None]
     return k, v, valid
 
 
 def paged_decode_attention(q: jnp.ndarray, state: PagedKVState,
-                           max_len: int) -> jnp.ndarray:
-    """q: [B, KVp, gp, hd] (one token) -> [B, KVp, gp, hd].
+                           max_len: int, layer=0) -> jnp.ndarray:
+    """q: [B, KVp, gp, hd] (one token) -> [B, KVp, gp, hd], against
+    layer ``layer``.
 
     Reference path: gathers the full KV then runs a dense softmax.  The
     serving engine uses the Pallas kernel (``kernels.ops.paged_attention``)
     instead; tests check the two agree.
     """
     import math
-    k, v, valid = gather_kv(state, max_len)
-    hd = q.shape[-1]
+    k, v, valid = gather_kv(state, max_len, layer)
+    b, kvp, _, hd = q.shape
+    k = k.reshape(b, max_len, kvp, hd)
+    v = v.reshape(b, max_len, kvp, hd)
     scores = jnp.einsum("bkgd,btkd->bkgt", q.astype(jnp.float32),
                         k.astype(jnp.float32)) / math.sqrt(hd)
     scores = jnp.where(valid[:, None, None, :], scores, -1e30)

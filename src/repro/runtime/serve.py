@@ -52,6 +52,7 @@ Two granularities of progress:
 """
 from __future__ import annotations
 
+import functools
 import struct
 import time
 import warnings
@@ -304,10 +305,11 @@ def _gather_block_rows(caches, rows):
             for key, st in caches.items()}
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=(0,))
 def _paste_block_rows(caches, kv, rows):
     """Import handed-off KV content into ``rows`` of every sublayer
-    pool (mirrored geometry); the receiving side of the handoff."""
+    pool (mirrored geometry); the receiving side of the handoff.  The
+    caches are donated: the rows are written in place."""
     out = {}
     for key, st in caches.items():
         k_b, v_b = kv[key]
@@ -512,23 +514,27 @@ class ContinuousBatchingEngine:
         # to the pre-sampling program — an all-greedy batch pays zero
         # sampling overhead, which matters on tiny configs where the
         # PRNG work rivals the forward pass) and a sampling one; the
-        # host picks per call based on the live slots
-        self._admit_step = jax.jit(self._admit_impl, static_argnums=(8,))
-        self._chunk_step = jax.jit(self._chunk_impl, static_argnums=(8,))
+        # host picks per call based on the live slots.  Every program
+        # that takes the caches donates them, so the pool is held once
+        # and written in place; the engine keeps only what they return
+        self._admit_step = jax.jit(self._admit_impl, static_argnums=(8,),
+                                   donate_argnums=(3,))
+        self._chunk_step = jax.jit(self._chunk_impl, static_argnums=(8,),
+                                   donate_argnums=(2,))
 
         def _decode_greedy(p, toks, caches):
             out = T.decode_step(p, cfg, toks, caches, plan, tp)
             # greedy sampling inside the program: one dispatch per step,
             # [B] ints on the wire instead of [B, V] logits
             return jnp.argmax(out.logits[:, -1], axis=-1), out.cache
-        self._decode = jax.jit(_decode_greedy)
+        self._decode = jax.jit(_decode_greedy, donate_argnums=(2,))
 
         def _decode_sampled(p, toks, caches, temps, topks, seeds, steps):
             out = T.decode_step(p, cfg, toks, caches, plan, tp)
             nxt = sample_logits(out.logits[:, -1], temps, topks, seeds,
                                 steps)
             return nxt, out.cache
-        self._decode_with = jax.jit(_decode_sampled)
+        self._decode_with = jax.jit(_decode_sampled, donate_argnums=(2,))
 
         # hardened variants: the same decode, plus per-block integrity
         # checksums of the *whole* pool fused into the dispatch — a
@@ -551,13 +557,13 @@ class ContinuousBatchingEngine:
         def _decode_greedy_h(p, toks, caches):
             nxt, caches = _decode_greedy(p, toks, caches)
             return nxt, caches, _cache_checksums(caches)
-        self._decode_h = jax.jit(_decode_greedy_h)
+        self._decode_h = jax.jit(_decode_greedy_h, donate_argnums=(2,))
 
         def _decode_sampled_h(p, toks, caches, temps, topks, seeds, steps):
             nxt, caches = _decode_sampled(p, toks, caches, temps, topks,
                                           seeds, steps)
             return nxt, caches, _cache_checksums(caches)
-        self._decode_with_h = jax.jit(_decode_sampled_h)
+        self._decode_with_h = jax.jit(_decode_sampled_h, donate_argnums=(2,))
 
     def reset_stats(self) -> None:
         """Zero the telemetry counters (post-jit-warmup)."""
@@ -700,10 +706,15 @@ class ContinuousBatchingEngine:
                         self.plan, self.tp)
         new_caches = {}
         for key, st in caches.items():
+            # every layer's rows in one scatter per pool; the table and
+            # lengths are the same at every layer
             dc = out.cache[key]
-            new_caches[key] = jax.vmap(
-                paging.write_prefill_batch,
-                in_axes=(0, 0, 0, None))(st, dc.k, dc.v, admit)
+            one = paging.write_prefill_batch(
+                st._replace(block_table=st.block_table[0],
+                            lengths=st.lengths[0]), dc.k, dc.v, admit)
+            new_caches[key] = one._replace(
+                block_table=st.block_table,
+                lengths=jnp.broadcast_to(one.lengths, st.lengths.shape))
         logits = out.logits[:, -1]
         if sampled:
             firsts = sample_logits(logits, temps, topks, seeds,
@@ -846,10 +857,9 @@ class ContinuousBatchingEngine:
             st = self.caches[key]
             which = int(rng.integers(2))
             pool = st.k_pool if which == 0 else st.v_pool
-            sh = pool.shape                  # [S, NB+1, P, KVp, hd]
+            sh = pool.shape                  # [S, NB+1, P, KVp * hd]
             coord = (int(rng.integers(sh[0])), b,
-                     int(rng.integers(sh[2])), int(rng.integers(sh[3])),
-                     int(rng.integers(sh[4])))
+                     int(rng.integers(sh[2])), int(rng.integers(sh[3])))
             nbits = jnp.dtype(pool.dtype).itemsize * 8
             bit = int(rng.integers(nbits))
             u = jnp.uint16 if nbits == 16 else jnp.uint32
@@ -1542,7 +1552,7 @@ class HandoffWireError(ValueError):
 #: checksum construction — and keep ``from_bytes`` rejecting everything
 #: it does not speak: a decode pod on an older image must fail loudly,
 #: never misparse.  Versioning rules: ``repro/serving/WIRE_FORMAT.md``.
-WIRE_VERSION = 1
+WIRE_VERSION = 2                       # 2: KV blocks with heads merged
 _WIRE_MAGIC = b"MPAI"
 _WIRE_HEADER = "<HQI"                  # version, payload length, checksum
 
@@ -1581,7 +1591,7 @@ class PrefillHandoff:
     engine.  Carries the first sampled token (the prefill stage owns
     admission sampling), the padded prompt length, and the block-level
     KV per sublayer — ``kv[key] = (k, v)`` with shape
-    ``[n_super, n_blocks, P, KVp, hd]`` — in the shared block geometry
+    ``[n_super, n_blocks, P, KVp * hd]`` — in the shared block geometry
     both mirrored pools were built with.
 
     The handoff is also a *wire format*: ``to_bytes``/``from_bytes``

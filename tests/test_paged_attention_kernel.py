@@ -1,6 +1,8 @@
 """Pallas paged-decode-attention kernel vs the gather_kv reference path
 (interpret mode): ragged lengths, partially-filled blocks, GQA head
-layouts, and the trash-row contract for inactive batch slots."""
+layouts, the layer index into a stacked pool, and the trash-row
+contract for inactive batch slots.  Pools are ``[L, rows, P, KVp*hd]``
+with the heads merged into the minor axis."""
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -20,23 +22,29 @@ IMPLS = {
 
 
 def _ragged_state(lengths, block_size, kv_heads, head_dim, num_blocks,
-                  dtype=jnp.float32, max_blocks=None):
-    """Build a paged cache holding random KV at the given ragged lengths,
-    allocated out of a shuffled free list (non-contiguous block rows)."""
+                  dtype=jnp.float32, max_blocks=None, layers=1):
+    """Build a paged cache holding random KV at the given ragged lengths
+    (different in every layer), allocated out of a shuffled free list
+    (non-contiguous block rows)."""
     b = len(lengths)
     alloc = BlockAllocator(num_blocks)
     RNG.shuffle(alloc.free)                       # rows land out of order
     state = init_paged_cache(b, num_blocks, block_size, kv_heads, head_dim,
-                             dtype=dtype, max_blocks=max_blocks)
+                             dtype=dtype, max_blocks=max_blocks,
+                             layers=layers)
     for t in range(max(lengths)):
         grow = np.array([1 if t < L else 0 for L in lengths])
         state = ensure_blocks(state, alloc, grow)
-        k = RNG.normal(size=(b, kv_heads, head_dim)).astype(np.float32)
-        v = RNG.normal(size=(b, kv_heads, head_dim)).astype(np.float32)
-        # only sequences still growing get a real write; freeze others by
-        # writing then restoring is overkill — instead append to all and
-        # rebuild lengths below (append_tokens advances every valid row)
-        state = append_tokens(state, jnp.asarray(k), jnp.asarray(v))
+        written = state.lengths
+        for layer in range(layers):
+            k = RNG.normal(size=(b, kv_heads, head_dim)).astype(np.float32)
+            v = RNG.normal(size=(b, kv_heads, head_dim)).astype(np.float32)
+            # only sequences still growing get a real write; freeze
+            # others by writing then restoring is overkill — instead
+            # append to all and rebuild lengths below (append_tokens
+            # advances every valid row, once per layer written)
+            state = append_tokens(state._replace(lengths=written),
+                                  jnp.asarray(k), jnp.asarray(v), layer)
         state = state._replace(lengths=jnp.asarray(
             np.minimum(np.asarray(state.lengths), lengths)))
     np.testing.assert_array_equal(np.asarray(state.lengths), lengths)
@@ -57,9 +65,30 @@ def test_kernel_matches_gather_reference(lengths, block_size, kv, gp, impl):
     q = jnp.asarray(RNG.normal(size=(len(lengths), kv, gp, hd))
                     .astype(np.float32))
     out = IMPLS[impl](q, state.k_pool, state.v_pool,
-                      state.block_table, state.lengths)
+                      state.block_table, state.lengths, 0)
     ref = paged_decode_attention(q, state, max_len=max(lengths))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-2)
+
+
+@pytest.mark.parametrize("hd", [64, 128])
+@pytest.mark.parametrize("layers", [1, 3])
+@pytest.mark.parametrize("impl", sorted(IMPLS))
+def test_kernel_reads_its_layer_of_a_stacked_pool(impl, layers, hd):
+    """The layer index selects one layer of the stacked pool in place:
+    the middle one of three (each holding different KV), or the only
+    one, at the served head widths."""
+    lengths = [9, 1, 6]
+    state = _ragged_state(lengths, 4, 2, hd, num_blocks=16, layers=layers)
+    assert state.k_pool.shape == (layers, 17, 4, 2 * hd)
+    layer = layers // 2
+    q = jnp.asarray(RNG.normal(size=(3, 2, 2, hd)).astype(np.float32))
+    out = IMPLS[impl](q, state.k_pool, state.v_pool, state.block_table,
+                      state.lengths, jnp.int32(layer))
+    ref = paged_decode_attention(q, state, max_len=9, layer=layer)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-2)
+    if layers > 1:                  # another layer's KV gives another answer
+        other = paged_decode_attention(q, state, max_len=9, layer=0)
+        assert np.abs(np.asarray(other) - np.asarray(ref)).max() > 0.1
 
 
 @pytest.mark.parametrize("impl", sorted(IMPLS))
@@ -69,7 +98,7 @@ def test_kernel_matches_reference_bf16_pool(impl):
                           dtype=jnp.bfloat16)
     q = jnp.asarray(RNG.normal(size=(2, 2, 2, 16)).astype(np.float32))
     out = IMPLS[impl](q, state.k_pool, state.v_pool,
-                      state.block_table, state.lengths)
+                      state.block_table, state.lengths, 0)
     ref = paged_decode_attention(q, state, max_len=11)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-2)
 
@@ -80,7 +109,8 @@ def test_kernel_ignores_blocks_past_length():
     state = _ragged_state(lengths, 4, 2, 8, num_blocks=16)
     ref_q = jnp.asarray(RNG.normal(size=(2, 2, 2, 8)).astype(np.float32))
     ref = np.asarray(ops.paged_attention(ref_q, state.k_pool, state.v_pool,
-                                         state.block_table, state.lengths))
+                                         state.block_table, state.lengths,
+                                         0))
     # poison every pool row not referenced within a live prefix
     table = np.asarray(state.block_table)
     live = set()
@@ -88,13 +118,14 @@ def test_kernel_ignores_blocks_past_length():
         live |= set(table[b, :-(-L // 4)].tolist())
     pk = np.asarray(state.k_pool).copy()
     pv = np.asarray(state.v_pool).copy()
-    for row in range(pk.shape[0]):
+    for row in range(pk.shape[1]):
         if row not in live:
-            pk[row] = 1e4
-            pv[row] = 1e4
+            pk[:, row] = 1e4
+            pv[:, row] = 1e4
     state = state._replace(k_pool=jnp.asarray(pk), v_pool=jnp.asarray(pv))
     out = np.asarray(ops.paged_attention(ref_q, state.k_pool, state.v_pool,
-                                         state.block_table, state.lengths))
+                                         state.block_table, state.lengths,
+                                         0))
     np.testing.assert_allclose(out, ref, atol=1e-2)
 
 
@@ -106,7 +137,7 @@ def test_zero_length_slot_attends_to_nothing(impl):
     state = state._replace(lengths=jnp.asarray(np.array([6, 0], np.int32)))
     q = jnp.asarray(RNG.normal(size=(2, 2, 2, 8)).astype(np.float32))
     out = np.asarray(IMPLS[impl](q, state.k_pool, state.v_pool,
-                                 state.block_table, state.lengths))
+                                 state.block_table, state.lengths, 0))
     assert np.isfinite(out).all()
     np.testing.assert_array_equal(out[1], np.zeros_like(out[1]))
 
@@ -118,10 +149,10 @@ def test_inactive_slot_appends_to_trash_row():
     state = init_paged_cache(2, 8, 4, 2, 8, dtype=jnp.float32)
     state = ensure_blocks(state, alloc, np.array([4, 0]))   # slot 1 inactive
     k = jnp.asarray(RNG.normal(size=(2, 2, 8)).astype(np.float32))
-    before = np.asarray(state.k_pool[:-1]).copy()           # live rows
+    before = np.asarray(state.k_pool[0, :-1]).copy()        # live rows
     state = append_tokens(state, k, k)
     np.testing.assert_array_equal(np.asarray(state.lengths), [1, 0])
-    after = np.asarray(state.k_pool[:-1])
+    after = np.asarray(state.k_pool[0, :-1])
     # only slot 0's first block changed; slot 1's write went to trash
     row0 = int(np.asarray(state.block_table)[0, 0])
     changed = [r for r in range(after.shape[0])

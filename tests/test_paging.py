@@ -32,11 +32,11 @@ def test_gather_reconstructs_written_tokens():
     alloc = BlockAllocator(32)
     state = init_paged_cache(B, 32, P, KV, HD, dtype=jnp.float32)
     state, ks, vs = _write_tokens(state, alloc, 10)
-    k, v, valid = gather_kv(state, 12)
-    np.testing.assert_allclose(np.asarray(k[:, :10]), np.asarray(ks),
-                               atol=1e-6)
-    np.testing.assert_allclose(np.asarray(v[:, :10]), np.asarray(vs),
-                               atol=1e-6)
+    k, v, valid = gather_kv(state, 12)         # heads merged: [B, T, KV*HD]
+    np.testing.assert_allclose(np.asarray(k[:, :10]),
+                               np.asarray(ks).reshape(B, 10, -1), atol=1e-6)
+    np.testing.assert_allclose(np.asarray(v[:, :10]),
+                               np.asarray(vs).reshape(B, 10, -1), atol=1e-6)
     assert bool(valid[:, :10].all()) and not bool(valid[:, 10:].any())
 
 
@@ -100,8 +100,8 @@ def test_write_prefill_roundtrips_through_gather():
     state = write_prefill(state, k, v, 0)
     assert int(state.lengths[0]) == s and int(state.lengths[1]) == 0
     gk, gv, valid = gather_kv(state, 8)
-    np.testing.assert_allclose(np.asarray(gk[0, :s]), np.asarray(k),
-                               atol=1e-6)
-    np.testing.assert_allclose(np.asarray(gv[0, :s]), np.asarray(v),
-                               atol=1e-6)
+    np.testing.assert_allclose(np.asarray(gk[0, :s]),
+                               np.asarray(k).reshape(s, -1), atol=1e-6)
+    np.testing.assert_allclose(np.asarray(gv[0, :s]),
+                               np.asarray(v).reshape(s, -1), atol=1e-6)
     assert bool(valid[0, :s].all()) and not bool(valid[0, s:].any())

@@ -79,6 +79,54 @@ def test_outputs_match_solo_flush_any_admission_order(model, workload,
         np.testing.assert_array_equal(out, solo_reference[rid])
 
 
+def _pools(eng):
+    return [a for st in eng.caches.values() for a in (st.k_pool, st.v_pool)]
+
+
+@pytest.mark.parametrize("harden", [False, True])
+def test_steps_donate_the_pool(model, workload, solo_reference, harden):
+    """Bucket admission and decode (plain or hardened) donate the caches:
+    after every step the pools the engine held before it are deleted, so
+    the pool is held once, and the outputs still match the solo runs."""
+    cfg, params = model
+    eng = ContinuousBatchingEngine(params, cfg, max_slots=3,
+                                   prompt_len=PROMPT_LEN, max_len=MAX_LEN,
+                                   block_size=BLOCK, harden=harden)
+    for rid, prompt, max_new in workload:
+        eng.submit(Request(rid, prompt, max_new=max_new))
+    steps = 0
+    while eng.pending:
+        before = _pools(eng)
+        eng.step()
+        assert all(a.is_deleted() for a in before)
+        assert not any(a.is_deleted() for a in _pools(eng))
+        steps += 1
+        assert steps < 1000, "engine failed to make progress"
+    for rid, _, _ in workload:
+        np.testing.assert_array_equal(eng.done[rid].output,
+                                      solo_reference[rid])
+
+
+def test_two_segment_plan_matches_one_segment(model, workload,
+                                              solo_reference):
+    """A plan of two segments hands each the whole pool at its layer
+    offset: the tokens are those of the one-segment runs."""
+    from repro.core.partition import PartitionPlan, Segment
+    from repro.core.precision import PrecisionPolicy
+    cfg, params = model
+    bf16 = PrecisionPolicy.bf16()
+    plan = PartitionPlan((Segment("a", 0, 1, bf16), Segment("b", 1, 2, bf16)))
+    eng = ContinuousBatchingEngine(params, cfg, plan, max_slots=3,
+                                   prompt_len=PROMPT_LEN, max_len=MAX_LEN,
+                                   block_size=BLOCK)
+    for rid, prompt, max_new in workload:
+        eng.submit(Request(rid, prompt, max_new=max_new))
+    _drain(eng)
+    for rid, _, _ in workload:
+        np.testing.assert_array_equal(eng.done[rid].output,
+                                      solo_reference[rid])
+
+
 def test_mid_decode_admission_and_exact_steps(model):
     """A long request decodes while short ones churn through the freed
     slots; total decode steps stay well under the windowed equivalent."""
@@ -330,6 +378,23 @@ def test_chunked_prefill_matches_windowed_at_full_length(model):
     ref.flush()
     np.testing.assert_array_equal(eng.done[0].output, ref.done[0].output)
     assert eng.alloc.available == eng.alloc.num_blocks
+
+
+def test_chunked_prefill_donates_the_pool(model):
+    """Each prefill chunk donates the caches it is handed."""
+    prompt = np.random.default_rng(12).integers(0, 256, 21).astype(np.int32)
+    eng = _long_engine(model)
+    eng.submit(Request(0, prompt, max_new=2))
+    chunk_step, donated = eng._chunk_step, []
+
+    def watched(*a):
+        out = chunk_step(*a)
+        donated.append(all(x.is_deleted() for st in a[2].values()
+                           for x in (st.k_pool, st.v_pool)))
+        return out
+    eng._chunk_step = watched
+    _drain(eng)
+    assert donated == [True] * 3                # 24 padded tokens, chunk 8
 
 
 def test_chunked_prefill_mixed_with_bucket_admissions(model):
