@@ -37,10 +37,10 @@ KERNEL_CONTRACTS: Dict[str, KernelContract] = {
     "paged_attention_pallas": KernelContract(
         module="src/repro/kernels/paged_attention.py",
         kernel_fn="_paged_kernel",
-        grid_rank=2,               # (sequence, table block); heads in-body
+        grid_rank=1,               # sequences; live pages walked in-body
         num_scalar_prefetch=3,     # block_table, lengths, layer ride ahead
-        tail_guard=True,           # dead-block predication + init/finalize
-        dimension_semantics=("parallel", "arbitrary"),
+        tail_guard=True,           # first fetch, fetch-ahead, empty slots
+        dimension_semantics=("arbitrary",),  # steps hand buffers on
         divisibility_assert=False,  # pool rows are whole pages by layout
         out_dtypes=("q.dtype",),
     ),

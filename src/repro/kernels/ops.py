@@ -94,10 +94,13 @@ def paged_attention_xla(q: jnp.ndarray, k_pool: jnp.ndarray,
         rows = jnp.maximum(block_table[:, j], 0)
         k = k_pool[layer, rows].reshape(b, page, kvp, hd)   # [B, P, KVp, hd]
         v = v_pool[layer, rows].reshape(b, page, kvp, hd)
-        k, v = k.astype(jnp.float32), v.astype(jnp.float32)
-        s = jnp.einsum("bkgd,bpkd->bkgp", qf, k)
         pos = j * page + jnp.arange(page)
         mask = pos[None, :] < lengths[:, None]        # [B, P]
+        # dead entries may name any row: zero their values too, so that
+        # a non-finite one cannot reach the output as 0 * inf
+        k = k.astype(jnp.float32)
+        v = jnp.where(mask[:, :, None, None], v.astype(jnp.float32), 0.0)
+        s = jnp.einsum("bkgd,bpkd->bkgp", qf, k)
         s = jnp.where(mask[:, None, None, :], s, -1e30)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         # zero masked contributions explicitly: for a fully-masked row

@@ -4,13 +4,11 @@ KV cache, walked through the block table — no KV materialization).
 The dense-gather decode path (`runtime.paging.gather_kv`) copies every
 sequence's KV out of the shared pool into a `[B, max_len, KVp, hd]`
 buffer on **every decode step** — O(B * max_len) HBM traffic before a
-single MXU cycle runs.  This kernel instead walks each sequence's block
-table directly inside the grid: the table and the per-sequence lengths
-ride in as scalar-prefetch operands, so the BlockSpec index map can DMA
-exactly the pool rows a sequence owns, and `pl.when` skips every block
-past the sequence's current length (no DMA'd-but-dead MXU work).
-Decode-step traffic drops from O(context) gather+attend to
-O(blocks-touched) attend.
+single MXU cycle runs.  This kernel instead walks each sequence's live
+pages straight out of the pool: the block table and the per-sequence
+lengths ride in as scalar-prefetch operands, and the kernel copies
+exactly the pool rows a sequence has written.  Decode-step work scales
+with the pages touched, not with the table's width.
 
 Block-table layout contract (shared with ``runtime.paging``):
 
@@ -28,18 +26,29 @@ Block-table layout contract (shared with ``runtime.paging``):
     ``block_table[b, j]`` at slot ``token % P``.
   * ``lengths``: ``[B] int32`` — tokens written per sequence.  Entries
     of ``block_table[b]`` at or past ``ceil(lengths[b] / P)`` are dead:
-    the index map clamps them to row 0 (the DMA must target *something*)
-    and the kernel body is predicated off, so they contribute nothing.
+    the kernel never reads them, so they may hold ``-1`` or any row.  A
+    sequence of length 0 touches no page and its output is exactly 0.
 
-Grid is ``(B, MB)`` with the block sweep innermost and "arbitrary"
-semantics, so the online-softmax statistics (m, l, acc) stay
-VMEM-resident across a sequence's whole table walk — the decode
-analogue of `flash_attention.py`'s KV sweep.  Each grid step DMAs one
-whole pool row, ``(1, 1, P, KVp * hd)`` at ``(layer, row, 0, 0)``, and
-walks the KVp heads inside the body with static lane slices: a block's
-last two dims then equal the pool's, which is what the TPU's (8, 128)
-tiling rule asks of a block that is not tile-aligned.  The layer index
-rides in as a third scalar-prefetch operand.
+The grid is ``(B,)``, one step per sequence, "arbitrary" because the
+steps hand buffers to each other.  The pools stay in HBM
+(``memory_space=pl.ANY``); a step walks its sequence's live pages in
+compute blocks of ``pages_per_block`` pages with a bounded loop, each
+page one asynchronous copy from its pool row into a VMEM buffer.  The
+buffers are doubled: block k+1's copies run behind block k's compute,
+and a sequence's last block starts the copies of the next sequence's
+first.  Pages of a block past the sequence's last live page are never
+copied; their buffer slots are masked out of the scores and the values.
+
+The body works all heads of a block at once.  The query is laid out
+block-diagonally, ``[KVp * gp, KVp * hd]`` with row ``(h, g)`` holding
+query ``g`` of KV head ``h`` in head ``h``'s lanes and zeros elsewhere,
+so one MXU contraction over the merged lane axis gives every head's
+scores against the block (``[KVp * gp, pages_per_block * P]``) and one
+more gives every head's ``p @ v``, of which each row keeps its own
+head's lanes.  The same form serves grouped (``gp > 1``) and ungrouped
+layouts.  Scores, the online-softmax statistics and ``p @ v`` are f32;
+the score contraction takes q and K in their common dtype (bf16 x bf16
+products are exact in f32) with f32 accumulation.
 """
 from __future__ import annotations
 
@@ -52,52 +61,116 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# tokens a compute block aims at, and the VMEM the doubled K/V buffers
+# and the block's f32 temporaries may take
+BLOCK_TOKENS = 256
+VMEM_BUDGET = 8 * 1024 * 1024
 
 
-def _paged_kernel(tbl_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_ref, l_ref, acc_ref, *, page: int, num_blk: int,
-                  kv_heads: int, scale: float):
-    b_idx = pl.program_id(0)
-    j = pl.program_id(1)
+def pages_per_block(page: int, width: int, itemsize: int,
+                    table_width: int) -> int:
+    """Pages a compute block takes: ``BLOCK_TOKENS`` worth, no more than
+    the table holds, and as many as fit ``VMEM_BUDGET`` (K and V, two
+    buffers each, plus the block's f32 copies of K and V)."""
+    per_page = page * width * (4 * itemsize + 2 * 4)
+    return max(1, min(BLOCK_TOKENS // page, table_width,
+                      VMEM_BUDGET // per_page))
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    seq_len = len_ref[b_idx]
+def _paged_kernel(tbl_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm, o_ref,
+                  k_buf, v_buf, sems, slot_ref, qbd_ref, m_ref, l_ref,
+                  acc_ref, *, scale: float):
+    b = pl.program_id(0)
+    n_seq = pl.num_programs(0)
+    _, ppb, page, width = k_buf.shape
+    rows, table_width = k_hbm.shape[1], tbl_ref.shape[1]
+    kvp, gp, hd = q_ref.shape[1:]
+    tokens = ppb * page
+    layer = layer_ref[0]
 
-    # Skip blocks entirely past this sequence's length: the DMA engine
-    # still fetched *a* row (the index map clamps dead table entries to
-    # row 0) but no head's matmuls are issued for it.
-    @pl.when(j * page < seq_len)
-    def _block():
-        gp, hd = q_ref.shape[2:]
-        pos = j * page + jax.lax.broadcasted_iota(jnp.int32, (gp, page), 1)
-        live = pos < seq_len
-        for h in range(kv_heads):                 # static walk over heads
-            lanes = slice(h * hd, (h + 1) * hd)
-            q = q_ref[0, h].astype(jnp.float32) * scale       # [gp, hd]
-            k = k_ref[0, 0, :, lanes].astype(jnp.float32)     # [P, hd]
-            v = v_ref[0, 0, :, lanes].astype(jnp.float32)
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-            s = jnp.where(live, s, NEG_INF)                   # [gp, P]
+    def live_pages(seq):
+        return jnp.minimum((len_ref[seq] + page - 1) // page, table_width)
 
-            m_prev = m_ref[h]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            corr = jnp.exp(m_prev - m_new)
-            l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=1, keepdims=True)
-            acc_ref[h] = acc_ref[h] * corr + jnp.dot(
-                p, v, preferred_element_type=jnp.float32)
-            m_ref[h] = m_new
+    def copies(seq, blk, slot, start):
+        """Start (or wait for) the copies of block ``blk`` of ``seq``
+        into buffer ``slot``: one per live page, none past them."""
+        first = blk * ppb
 
-    @pl.when(j == num_blk - 1)
-    def _finalize():
-        o_ref[0] = (acc_ref[...] /
-                    jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        def page_copy(i, carry):
+            row = jnp.clip(tbl_ref[seq, first + i], 0, rows - 1)
+            for n, (pool, buf) in enumerate(((k_hbm, k_buf),
+                                             (v_hbm, v_buf))):
+                cp = pltpu.make_async_copy(pool.at[layer, row],
+                                           buf.at[slot, i], sems.at[n, slot])
+                if start:
+                    cp.start()
+                else:
+                    cp.wait()
+            return carry
+
+        jax.lax.fori_loop(0, jnp.clip(live_pages(seq) - first, 0, ppb),
+                          page_copy, 0)
+
+    def fetch_ahead(seq, blk, slot):
+        @pl.when(seq < n_seq)
+        def _ahead():
+            copies(seq, blk, slot, start=True)
+
+    @pl.when(b == 0)
+    def _first():                 # later sequences are fetched ahead
+        slot_ref[0] = 0
+        copies(0, 0, 0, start=True)
+
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    qbd_ref[...] = jnp.zeros_like(qbd_ref)
+    for h in range(kvp):              # the block-diagonal query
+        qbd_ref[h * gp:(h + 1) * gp, h * hd:(h + 1) * hd] = (
+            q_ref[0, h].astype(qbd_ref.dtype))
+
+    seq_len = len_ref[b]
+    n_blk = (live_pages(b) + ppb - 1) // ppb
+
+    def block(blk, slot):
+        nxt = 1 - slot
+        more = blk + 1 < n_blk        # else the next sequence's first block
+        fetch_ahead(jnp.where(more, b, b + 1), jnp.where(more, blk + 1, 0),
+                    nxt)
+        copies(b, blk, slot, start=False)
+        pos = blk * tokens + jax.lax.broadcasted_iota(jnp.int32,
+                                                      (1, tokens), 1)
+        live_col = (blk * tokens + jax.lax.broadcasted_iota(
+            jnp.int32, (tokens, 1), 0)) < seq_len
+        k = k_buf[slot].reshape(tokens, width).astype(qbd_ref.dtype)
+        v = jnp.where(live_col,
+                      v_buf[slot].reshape(tokens, width).astype(jnp.float32),
+                      0.0)
+        s = jax.lax.dot_general(qbd_ref[...], k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        s = jnp.where(pos < seq_len, s, NEG_INF)          # [KVp*gp, T]
+
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jnp.dot(
+            p, v, preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+        return nxt
+
+    slot = jax.lax.fori_loop(0, n_blk, block, slot_ref[0])
+
+    @pl.when(n_blk == 0)
+    def _empty():                 # nothing in flight: fetch ahead here
+        fetch_ahead(b + 1, 0, slot)
+
+    slot_ref[0] = slot
+    for h in range(kvp):              # each row keeps its own head's lanes
+        r = slice(h * gp, (h + 1) * gp)
+        o_ref[0, h] = (acc_ref[r, h * hd:(h + 1) * hd] /
+                       jnp.maximum(l_ref[r], 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -113,33 +186,34 @@ def paged_attention_pallas(q: jnp.ndarray, k_pool: jnp.ndarray,
     """
     b, kvp, gp, hd = q.shape
     n_layers, _, page, width = k_pool.shape
-    mb = block_table.shape[1]
-    scale = 1.0 / math.sqrt(hd)
-    kernel = functools.partial(_paged_kernel, page=page, num_blk=mb,
-                               kv_heads=kvp, scale=scale)
+    ppb = pages_per_block(page, width, k_pool.dtype.itemsize,
+                          block_table.shape[1])
+    mxu_dtype = jnp.promote_types(q.dtype, k_pool.dtype)
+    kernel = functools.partial(_paged_kernel, scale=1.0 / math.sqrt(hd))
 
-    def q_map(i, j, tbl, lens, lay):
+    def q_map(i, tbl, lens, lay):
         return (i, 0, 0, 0)
-
-    def kv_map(i, j, tbl, lens, lay):
-        # dead entries (-1) clamp to row 0; the body is predicated off
-        return (jnp.clip(lay[0], 0, n_layers - 1),
-                jnp.maximum(tbl[i, j], 0), 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, mb),
+        grid=(b,),
         in_specs=[
             pl.BlockSpec((1, kvp, gp, hd), q_map),
-            pl.BlockSpec((1, 1, page, width), kv_map),
-            pl.BlockSpec((1, 1, page, width), kv_map),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=pl.BlockSpec((1, kvp, gp, hd), q_map),
         scratch_shapes=[
+            # doubled page buffers and their copy semaphores [K|V, slot]
+            pltpu.VMEM((2, ppb, page, width), k_pool.dtype),
+            pltpu.VMEM((2, ppb, page, width), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),      # the next sequence's slot
+            pltpu.VMEM((kvp * gp, width), mxu_dtype),
             # VMEM-resident online-softmax statistics across the walk
-            pltpu.VMEM((kvp, gp, 1), jnp.float32),
-            pltpu.VMEM((kvp, gp, 1), jnp.float32),
-            pltpu.VMEM((kvp, gp, hd), jnp.float32),
+            pltpu.VMEM((kvp * gp, 1), jnp.float32),
+            pltpu.VMEM((kvp * gp, 1), jnp.float32),
+            pltpu.VMEM((kvp * gp, width), jnp.float32),
         ],
     )
     return pl.pallas_call(
@@ -150,7 +224,8 @@ def paged_attention_pallas(q: jnp.ndarray, k_pool: jnp.ndarray,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvp, gp, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(block_table, lengths, jnp.reshape(layer, (1,)).astype(jnp.int32),
+    )(block_table, lengths,
+      jnp.clip(jnp.reshape(layer, (1,)), 0, n_layers - 1).astype(jnp.int32),
       q, k_pool, v_pool)

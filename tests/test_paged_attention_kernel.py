@@ -1,6 +1,7 @@
 """Pallas paged-decode-attention kernel vs the gather_kv reference path
 (interpret mode): ragged lengths, partially-filled blocks, GQA head
-layouts, the layer index into a stacked pool, and the trash-row
+layouts, the served layouts over several compute blocks, the layer
+index into a stacked pool, dead table entries, and the trash-row
 contract for inactive batch slots.  Pools are ``[L, rows, P, KVp*hd]``
 with the heads merged into the minor axis."""
 import numpy as np
@@ -8,10 +9,11 @@ import jax.numpy as jnp
 import pytest
 
 from repro.kernels import ops
-from repro.kernels.paged_attention import paged_attention_pallas
-from repro.runtime.paging import (BlockAllocator, append_tokens,
-                                  ensure_blocks, init_paged_cache,
-                                  paged_decode_attention)
+from repro.kernels.paged_attention import (pages_per_block,
+                                           paged_attention_pallas)
+from repro.runtime.paging import (BlockAllocator, PagedKVState,
+                                  append_tokens, ensure_blocks,
+                                  init_paged_cache, paged_decode_attention)
 
 RNG = np.random.default_rng(7)
 
@@ -158,3 +160,83 @@ def test_inactive_slot_appends_to_trash_row():
     changed = [r for r in range(after.shape[0])
                if not np.array_equal(before[r], after[r])]
     assert changed == [row0], changed
+
+
+# (KVp, gp, hd) of the served pools: stablelm-1.6b (32 kv heads of 64,
+# no grouping) and mistral-7b (8 kv heads x 4 of 128)
+SERVED = {"stablelm-1.6b": (32, 1, 64), "mistral-7b": (8, 4, 128)}
+
+
+def _served_pool(lengths, page, kvp, hd, mb, dead):
+    """bf16 pools of one layer holding random KV, each sequence's live
+    pages on shuffled rows of their own, with every other row (the
+    trash row too) filled with NaN.  Table entries past a sequence's
+    live pages hold -1 (``dead="unallocated"``) or name the NaN rows
+    (``dead="nan_rows"``, which also fills the unwritten tail of each
+    last live page with NaN).  Returns the poisoned state and a clean copy
+    whose dead entries hold -1 and whose spare rows are finite, for the
+    gather reference (which reads dead entries as row 0)."""
+    pages = [-(-n // page) for n in lengths]
+    spare = 4
+    rows = sum(pages) + spare + 1
+    order = RNG.permutation(rows - 1)
+    live_rows, nan_rows = order[:sum(pages)], order[sum(pages):]
+    shape = (1, rows, page, kvp * hd)
+    k = RNG.normal(size=shape).astype(np.float32)
+    v = RNG.normal(size=shape).astype(np.float32)
+    table = -np.ones((len(lengths), mb), np.int32)
+    at = 0
+    for b, n in enumerate(pages):
+        table[b, :n] = live_rows[at:at + n]
+        at += n
+    clean = PagedKVState(jnp.asarray(k, jnp.bfloat16),
+                         jnp.asarray(v, jnp.bfloat16), jnp.asarray(table),
+                         jnp.asarray(np.asarray(lengths, np.int32)))
+    for pool in (k, v):
+        pool[:, nan_rows] = np.nan
+        pool[:, rows - 1] = np.nan
+    if dead == "nan_rows":
+        for b, n in enumerate(pages):
+            table[b, n:] = RNG.choice(nan_rows, mb - n)
+            if lengths[b] % page:          # the last page's unwritten tail
+                k[:, table[b, n - 1], lengths[b] % page:] = np.nan
+                v[:, table[b, n - 1], lengths[b] % page:] = np.nan
+    poisoned = clean._replace(k_pool=jnp.asarray(k, jnp.bfloat16),
+                              v_pool=jnp.asarray(v, jnp.bfloat16),
+                              block_table=jnp.asarray(table))
+    return poisoned, clean
+
+
+@pytest.mark.parametrize("dead", ["unallocated", "nan_rows"])
+@pytest.mark.parametrize("layout", sorted(SERVED))
+@pytest.mark.parametrize("impl", sorted(IMPLS))
+def test_served_layouts_read_only_live_pages(impl, layout, dead):
+    """At the served layouts and 16-token pages, with a table three
+    compute blocks wide: lengths that end mid-page and mid-block, one
+    whose pages span every block, one exactly a block long, and an empty
+    slot beside a full one.  Dead table entries, -1 or naming NaN rows,
+    and NaN past a length inside its last page do not reach the output;
+    the empty slot reads exactly 0."""
+    kvp, gp, hd = SERVED[layout]
+    page = 16
+    ppb = pages_per_block(page, kvp * hd, 2, 3 * 8)
+    assert ppb > 1
+    mb = 3 * ppb
+    blk = ppb * page
+    lengths = [2 * blk + 3 * page + 5,  # three blocks, ends mid-page
+               0,                       # empty, beside a full slot
+               mb * page,               # every entry live
+               blk // 2 + 3,            # mid-page, mid-block
+               blk]                     # exactly one block
+    state, clean = _served_pool(lengths, page, kvp, hd, mb, dead)
+    q = jnp.asarray(RNG.normal(size=(len(lengths), kvp, gp, hd)),
+                    jnp.bfloat16)
+    out = np.asarray(IMPLS[impl](q, state.k_pool, state.v_pool,
+                                 state.block_table, state.lengths, 0),
+                     np.float32)
+    ref = np.asarray(paged_decode_attention(q, clean, max_len=mb * page),
+                     np.float32)
+    assert np.isfinite(out).all()
+    np.testing.assert_array_equal(out[1], np.zeros_like(out[1]))
+    live = [b for b, n in enumerate(lengths) if n]
+    np.testing.assert_allclose(out[live], ref[live], atol=2e-2)

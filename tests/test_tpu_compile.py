@@ -60,15 +60,25 @@ def no_persistent_cache():
 # (KVp, gp, hd): stablelm-1.6b as served (32 kv heads of 64, no
 # grouping) and a grouped-query layout (8 kv heads x 5 of 128)
 LAYOUTS = {"stablelm-1.6b": (32, 1, 64), "gqa-8x5": (8, 5, 128)}
+# the kernel also at mistral-7b's served layout (8 kv heads x 4 of 128)
+KERNEL_LAYOUTS = {**LAYOUTS, "mistral-7b": (8, 4, 128)}
+# id -> (layout, P, slots, table entries, layers): every layout at 16-
+# and 8-token pages (8: the PoolSpec default) over 1024-token tables, and
+# the benchmark cells' served pools, whose table widths set how many
+# pages a compute block takes and so what the kernel holds in VMEM
+KERNEL_CASES = {
+    **{f"{layout}-{page}": (layout, page, 8, 1024 // page, 3)
+       for layout in sorted(KERNEL_LAYOUTS) for page in (16, 8)},
+    "stablelm-1.6b-chat-16x80": ("stablelm-1.6b", 16, 16, 80, 24),
+    "mistral-7b-decode-long-32x72": ("mistral-7b", 16, 32, 72, 16),
+}
 
 
-@pytest.mark.parametrize("page", [16, 8])     # 8: the PoolSpec default
-@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
 def test_paged_attention_compiles_for_v5e(one_chip, no_persistent_cache,
-                                          layout, page):
-    kvp, gp, hd = LAYOUTS[layout]
-    batch, max_len, layers = 8, 1024, 3
-    mb = max_len // page
+                                          case):
+    layout, page, batch, mb, layers = KERNEL_CASES[case]
+    kvp, gp, hd = KERNEL_LAYOUTS[layout]
     rows = batch * mb + 1                     # + the trash row
 
     def arg(shape, dtype):
